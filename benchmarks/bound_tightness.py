@@ -44,6 +44,9 @@ def check_paper_claim() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     out = check_paper_claim()
     print(f"max p(bright) for xi=1.5 in 0.1<L<0.9: "
           f"{out['claim_max_p_bright']:.5f} "

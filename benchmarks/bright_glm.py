@@ -28,7 +28,7 @@ from repro import api
 from repro.analysis.kernels import derive_traffic
 from repro.core import brightness, flymc
 from repro.kernels.bright_glm.ops import bright_glm
-from repro.kernels.common import default_interpret
+from repro.kernels.common import default_interpret, pad_to
 
 
 def _bytes_model(n: int, d: int, capacity: int) -> dict:
@@ -46,7 +46,7 @@ def _bytes_model(n: int, d: int, capacity: int) -> dict:
     s, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
     (model,) = derive_traffic(
         lambda *a: bright_glm(*a, interpret=True),
-        s((n, d), f32), s((n,), f32), s((n,), f32),
+        s((n, 1, pad_to(d, 128)), f32), s((n,), f32), s((n,), f32),
         s((c,), i32), s((), i32), s((d,), f32),
     ).values()
     return {
@@ -75,7 +75,7 @@ def bench(n=5000, d=21, capacity=1024, iters=300, q_db=0.01, reps=3):
         state = jax.jit(alg.init)(jax.random.key(1), alg.default_position)
         idx, mask = brightness.bright_buffer(state.bright, capacity)
         f = jax.jit(
-            flymc.make_joint_logpost(alg.spec, tuned.data, tuned.stats,
+            flymc.make_joint_logpost(alg.spec, alg.data, tuned.stats,
                                      idx, mask)
         )
         theta = state.sampler.theta
@@ -133,6 +133,9 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -110,6 +110,9 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -38,6 +38,9 @@ def main(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     rec = main()
     status = "OK" if rec["ok"] else "FAIL"
     print(f"static_analysis: {status} "

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +49,7 @@ def _finish(trace, burn):
 
 
 def _run_flymc(model, kernel, theta0, key, iters, burn, q_db, step0):
-    cap = max(256, int(0.05 * model.data.x.shape[0]))
+    cap = capacity_for(model.data.x.shape[0])
     alg = api.firefly(
         model, kernel=kernel, capacity=cap, cand_capacity=cap, q_db=q_db,
         step_size=step0, adapt_target="auto",
@@ -109,38 +110,63 @@ def run_experiment(
     return results
 
 
+@dataclasses.dataclass(frozen=True)
+class Problem:
+    """One of the paper's three experiments at its published shape."""
+
+    name: str
+    n: int  # rows (the paper's N)
+    d: int  # features
+    kernel: str  # θ-kernel
+    step0: float  # initial step size
+    q_untuned: float  # q_db for untuned FlyMC
+    q_tuned: float  # q_db for MAP-tuned FlyMC
+    build: Callable  # (key, n) -> GLMModel on synthetic rows of this shape
+
+
+def _mnist(key, n):
+    data = logistic_data(key, n=n, d=51, separation=2.0)
+    return GLMModel.logistic(data, prior_scale=1.0, xi=1.5)
+
+
+def _cifar(key, n):
+    data = softmax_data(key, n=n, d=256, k=3)
+    return GLMModel.softmax(data, n_classes=3, prior_scale=1.0)
+
+
+def _opv(key, n):
+    data, _ = robust_data(key, n=n, d=57, nu=4.0)
+    return GLMModel.robust(data, nu=4.0, sigma=1.0, prior_scale=1.0)
+
+
+PROBLEMS = (
+    # §4.1 — MNIST 7v9 logistic regression, random-walk MH
+    Problem("mnist-logistic-rwmh", 12_214, 51, "rwmh", 0.02, 0.1, 0.01,
+            _mnist),
+    # §4.2 — CIFAR-3 softmax classification, MALA
+    Problem("cifar-softmax-mala", 18_000, 256, "mala", 0.002, 0.1, 0.01,
+            _cifar),
+    # §4.3 — OPV robust regression, slice sampling
+    Problem("opv-robust-slice", 1_800_000, 57, "slice", 0.05, 0.1, 0.01,
+            _opv),
+)
+
+
+def capacity_for(n: int) -> int:
+    """Bright and candidate buffer capacity the Table-1 runs start from."""
+    return max(256, int(0.05 * n))
+
+
 def table1(scale: float = 1.0, iters: int = 3000, burn: int = 750,
            opv_n: int = 200_000, seed: int = 0) -> list[AlgoResult]:
     key = jax.random.key(seed)
-    k1, k2, k3 = jax.random.split(key, 3)
     out: list[AlgoResult] = []
-
-    # §4.1 — MNIST 7v9 logistic regression, random-walk MH
-    n1 = int(12_214 * scale)
-    data = logistic_data(k1, n=n1, d=51, separation=2.0)
-    model = GLMModel.logistic(data, prior_scale=1.0, xi=1.5)
-    out += run_experiment(
-        "mnist-logistic-rwmh", model, "rwmh", k1, iters, burn,
-        step0=0.02, q_untuned=0.1, q_tuned=0.01,
-    )
-
-    # §4.2 — CIFAR-3 softmax classification, MALA
-    n2 = int(18_000 * scale)
-    data = softmax_data(k2, n=n2, d=256, k=3)
-    model = GLMModel.softmax(data, n_classes=3, prior_scale=1.0)
-    out += run_experiment(
-        "cifar-softmax-mala", model, "mala", k2, iters, burn,
-        step0=0.002, q_untuned=0.1, q_tuned=0.01,
-    )
-
-    # §4.3 — OPV robust regression, slice sampling
-    n3 = int(opv_n * scale)
-    data, _ = robust_data(k3, n=n3, d=57, nu=4.0)
-    model = GLMModel.robust(data, nu=4.0, sigma=1.0, prior_scale=1.0)
-    out += run_experiment(
-        "opv-robust-slice", model, "slice", k3, iters, burn,
-        step0=0.05, q_untuned=0.1, q_tuned=0.01,
-    )
+    sizes = (PROBLEMS[0].n, PROBLEMS[1].n, opv_n)
+    for p, k, n in zip(PROBLEMS, jax.random.split(key, 3), sizes):
+        out += run_experiment(
+            p.name, p.build(k, int(n * scale)), p.kernel, k, iters, burn,
+            step0=p.step0, q_untuned=p.q_untuned, q_tuned=p.q_tuned,
+        )
     return out
 
 
@@ -159,6 +185,9 @@ def format_results(results: list[AlgoResult]) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

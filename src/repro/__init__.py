@@ -14,6 +14,4 @@ Layers:
   repro.configs      — one config per assigned architecture + paper experiments
 """
 
-from repro import compat  # noqa: F401  (jax forward-compat polyfills)
-
 __version__ = "1.1.0"

@@ -464,7 +464,14 @@ class BoundsInterpreter:
             lo, x, hi = iv(0), iv(1), iv(2)
             out(x.max_(lo).min_(hi))
         elif name in ("div", "floor_divide"):
-            out(self._div(iv(0), iv(1)))
+            q = self._div(iv(0), iv(1))
+            dtype = getattr(_aval_of(eqn.outvars[0]), "dtype", np.float32)
+            if np.issubdtype(dtype, np.integer) and q is not TOP:
+                # Integer division rounds (monotonically) to an integer.
+                rnd = math.trunc if name == "div" else math.floor
+                whole = lambda v: v if math.isinf(v) else float(rnd(v))
+                q = Interval(whole(q.lo), whole(q.hi))
+            out(q)
         elif name == "rem":
             out(self._rem(iv(0), iv(1)))
         elif name == "convert_element_type":

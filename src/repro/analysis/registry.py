@@ -548,15 +548,15 @@ def _s(shape, dtype=jnp.float32):
 def _bright_fn(family, **kw):
     from repro.kernels.bright_glm.ops import bright_glm
 
-    def fn(x, t, xi, idx, nb, theta):
-        return bright_glm(x, t, xi, idx, nb, theta, family=family,
+    def fn(x_rows, t, xi, idx, nb, theta):
+        return bright_glm(x_rows, t, xi, idx, nb, theta, family=family,
                           interpret=True, **kw)
 
     return fn
 
 
 def _bright_args(family):
-    x = _s((N, D))
+    x = _s((N, 1, _DP))  # gather_layout(x)
     idx = _s((CAPACITY,), jnp.int32)
     nb = _s((), jnp.int32)
     if family == "softmax":
@@ -613,7 +613,7 @@ def _kernel_bright_chains() -> Report:
             _s((_KD, D)))
     return check(
         fn, *args,
-        rules=kernel_rules(accumulators={1: (1,)},
+        rules=kernel_rules(accumulators={1: (0, 1)},  # whole-(K, 1) SMEM
                            expected_bytes={"kernel": _KD * _BRIGHT_BYTES}),
         name="kernel.bright_glm.chains",
     )
@@ -652,10 +652,12 @@ def _kernel_z_update() -> Report:
 
 @entry_point("kernel.z_update.chains")
 def _kernel_z_chains() -> Report:
+    # The count is one whole (K, 1) SMEM block every grid step revisits,
+    # along the chain axis too; each chain owns its own row of it.
     return check(
         jax.vmap(_z_fn()), _s((_KD, _ZN), jnp.int32), _s((_KD,), jnp.int32),
         _s((_KD, 2), jnp.int32),
-        rules=kernel_rules(accumulators={0: (1,), 1: (1,)},
+        rules=kernel_rules(accumulators={0: (1,), 1: (0, 1)},
                            expected_bytes={"kernel": _KD * _Z_BYTES}),
         name="kernel.z_update.chains",
     )

@@ -52,15 +52,18 @@ class SamplingAlgorithm:
     ``step_data``/``data``/``stats`` are the dataset-as-operand form of the
     step: ``step_data(key, state, data, stats)`` is ``step`` with the
     dataset and its sufficient statistics passed as arguments instead of
-    closed over. When present, the driver threads ``alg.data``/``alg.stats``
-    through the jitted chunk as traced operands rather than baking them in
-    as compile-time constants. ``step_chains_data`` is the chain-batched
-    counterpart (``(keys (K,), state (K, ...), data, stats)``) for
-    algorithms whose batching is not vmap — the distributed fleet supplies
-    one that shard_maps the chain axis with the dataset replicated as an
-    operand, so even a sharded fleet's chunk jit carries no dataset
-    constant (the :mod:`repro.analysis` closure-constant rule pins this). This is a bitwise-visible choice, not a
-    plumbing detail: XLA's constant folding rounds data-dependent
+    closed over, and ``init_data(key, position, data, stats)`` the same
+    form of ``init``. When present, the driver threads ``alg.data``/
+    ``alg.stats`` through the jitted init and chunks as traced operands
+    rather than baking them in as compile-time constants (at the paper's
+    N = 1.8M a baked-in dataset is a GB of executable).
+    ``step_chains_data`` is the chain-batched counterpart
+    (``(keys (K,), state (K, ...), data, stats)``) for algorithms whose
+    batching is not vmap — the distributed fleet supplies one that
+    shard_maps the chain axis with the dataset replicated as an operand,
+    so even a sharded fleet's chunk jit carries no dataset constant (the
+    :mod:`repro.analysis` closure-constant rule pins this). This is a
+    bitwise-visible choice, not a plumbing detail: XLA's constant folding rounds data-dependent
     reductions differently for a baked-in dataset than for the identical
     values passed as an operand (low-bit ``joint_lp``/``accept_prob``
     differences on CPU, observed at e.g. N=512, D=8). The operand form is
@@ -81,6 +84,7 @@ class SamplingAlgorithm:
     step_chains: Callable[[jax.Array, Any], tuple[Any, StepStats]] | None = None
     init_chains: Callable[[jax.Array, Any], Any] | None = None
     step_data: Callable[..., tuple[Any, StepStats]] | None = None
+    init_data: Callable[..., Any] | None = None
     step_chains_data: Callable[..., tuple[Any, StepStats]] | None = None
     data: Any = None
     stats: Any = None
@@ -266,10 +270,16 @@ def _firefly_from_spec(
     spec: FlyMCSpec, data: GLMData, stats: CollapsedStats, step_size: float
 ) -> SamplingAlgorithm:
     n = data.x.shape[0]
+    if spec.backend == "pallas":
+        data = bounds_lib.with_gather_layout(data)
+    stats = bounds_lib.recenter(spec.bound, stats)
 
     def init(key, position):
+        return init_data(key, position, data, stats)
+
+    def init_data(key, position, data_, stats_):
         return flymc.init_chain_state(
-            spec, data, stats, position, key, step_size=step_size
+            spec, data_, stats_, position, key, step_size=step_size
         )
 
     def step(key, state):
@@ -316,6 +326,7 @@ def _firefly_from_spec(
         default_position=default_position,
         spec=spec,
         step_data=step_data,
+        init_data=init_data,
         data=data,
         stats=stats,
     )
@@ -364,8 +375,14 @@ def regular_mcmc(
     Step-size adaptation (``adapt_target``) is warmup-only, exactly like
     :func:`firefly`: the update freezes after ``num_warmup`` iterations.
     """
+    data = None
     if model is not None:
-        logdensity_fn = logdensity_fn or model.full_logpdf_fn()
+        if logdensity_fn is None:
+            # Operand form: the driver passes the rows in, so no jit
+            # bakes the dataset into its executable.
+            data = model.data
+            density_of = model.full_logpdf_fn
+            logdensity_fn = density_of(data)
         n_data = n_data if n_data is not None else model.data.x.shape[0]
         theta_shape = theta_shape or model.theta_shape
     if logdensity_fn is None or n_data is None:
@@ -373,19 +390,18 @@ def regular_mcmc(
     ks = samplers.get_kernel(kernel)
     if adapt_target == "auto":
         adapt_target = None if ks.target_accept >= 1.0 else ks.target_accept
-    kern = samplers.bind(kernel, logdensity_fn, kernel_params)
     n = jnp.int32(n_data)
 
-    def init(key, position):
-        del key
-        st = samplers.init_state(logdensity_fn, position, with_grad=ks.needs_grad)
+    def init_with(density, position):
+        st = samplers.init_state(density, position, with_grad=ks.needs_grad)
         return MCMCState(
             sampler=st,
             log_step=jnp.log(jnp.asarray(step_size, st.lp.dtype)),
             iteration=jnp.int32(0),
         )
 
-    def step(key, state):
+    def step_with(density, key, state):
+        kern = samplers.bind(kernel, density, kernel_params)
         new, info = kern(key, state.sampler, jnp.exp(state.log_step))
         log_step = state.log_step
         if adapt_target is not None:
@@ -407,9 +423,19 @@ def regular_mcmc(
         )
         return out, stats
 
+    operand_forms = {}
+    if data is not None:
+        operand_forms = dict(
+            init_data=lambda key, pos, d, _: init_with(density_of(d), pos),
+            step_data=lambda key, st, d, _: step_with(density_of(d), key, st),
+            data=data,
+        )
     default_position = (
         jnp.zeros(theta_shape) if theta_shape is not None else None
     )
     return SamplingAlgorithm(
-        init=init, step=step, default_position=default_position
+        init=lambda key, position: init_with(logdensity_fn, position),
+        step=lambda key, state: step_with(logdensity_fn, key, state),
+        default_position=default_position,
+        **operand_forms,
     )

@@ -501,6 +501,15 @@ def sample(
                 "no init_position given and the algorithm has no default"
             )
         def init_fn(alg):
+            if alg.init_data is not None and alg.data is not None:
+                # The operand form, like the chunks: no dataset constant.
+                # A chain-sharded fleet inits here too, so its chains start
+                # bitwise where a one-device batch starts.
+                f = alg.init_data
+                fn = _cached(("init_data", f, multi), lambda: jax.jit(
+                    jax.vmap(f, in_axes=(0, 0, None, None)) if multi else f
+                ))
+                return lambda k, p: fn(k, p, alg.data, alg.stats)
             build = lambda: jax.jit(alg.batched_init() if multi else alg.init)
             return _cached(
                 ("init", alg.init, alg.init_chains, multi), build

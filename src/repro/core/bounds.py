@@ -33,13 +33,23 @@ Every bound exposes the same surface:
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import numerics
 from repro.core.numerics import jj_a as _jj_a
 from repro.core.numerics import jj_c as _jj_c
+
+
+# Every product of data and parameters runs at f32: XLA's TPU default
+# rounds f32 matmul operands to bf16, which at N = 1.8M quantizes θ to
+# about the posterior's own width. (On CPU the precision is f32 either way.)
+_HIGHEST = jax.lax.Precision.HIGHEST
+_mm = partial(jnp.matmul, precision=_HIGHEST)
+_einsum = partial(jnp.einsum, precision=_HIGHEST)
 
 
 class GLMData(NamedTuple):
@@ -50,17 +60,33 @@ class GLMData(NamedTuple):
                 or real-valued response (robust regression)
     xi : per-datum bound-tightness parameter. Shape (N,) for logistic/robust,
          (N, K) tangency logits for softmax.
+    x_rows : x in the fused bright-GLM kernel's gather layout, (N, 1, Dp)
+         (:func:`repro.kernels.common.gather_layout`), or None. Built once
+         per dataset by :func:`with_gather_layout` for ``backend="pallas"``
+         so no evaluation re-lays the dataset out.
     """
 
     x: jax.Array
     t: jax.Array
     xi: jax.Array
+    x_rows: jax.Array | None = None
+
+
+def with_gather_layout(data: GLMData) -> GLMData:
+    """``data`` carrying ``x_rows``, built from ``x`` unless already there."""
+    if data.x_rows is not None:
+        return data
+    from repro.kernels.common import gather_layout
+
+    return data._replace(x_rows=gather_layout(data.x))
 
 
 class CollapsedStats(NamedTuple):
     """Sufficient statistics of a product of quadratic log-bounds.
 
-    For vector-parameter bounds: ``Σ log B = θᵀ·Q·θ + q·θ + c``.
+    For vector-parameter bounds: ``Σ log B = θᵀ·Q·θ + q·θ + c``, or, once
+    :func:`recenter` has set ``ref``, ``Δᵀ·Q·Δ + q·Δ + c`` with
+    Δ = θ - ref (the same quadratic, expanded about ``ref``).
     For the softmax (matrix θ of shape (K, D)): ``Q`` holds S=Σxxᵀ (D,D),
     ``q`` holds R=Σ x rᵀ (D,K) and the quadratic is -½tr(AθSθᵀ)+tr(θR)+c.
     """
@@ -68,11 +94,41 @@ class CollapsedStats(NamedTuple):
     Q: jax.Array
     q: jax.Array
     c: jax.Array
+    ref: jax.Array | None = None
 
 
 def psum_stats(stats: CollapsedStats, axis_name) -> CollapsedStats:
     """All-reduce suff-stats across data shards (one-time setup collective)."""
-    return CollapsedStats(*(jax.lax.psum(s, axis_name) for s in stats))
+    assert stats.ref is None, "psum the raw stats, then recenter"
+    psum = lambda s: jax.lax.psum(s, axis_name)
+    return CollapsedStats(psum(stats.Q), psum(stats.q), psum(stats.c))
+
+
+def recenter(bound, stats: CollapsedStats) -> CollapsedStats:
+    """``stats`` of a vector-θ quadratic bound, expanded about its maximum.
+
+    At N = 1.8M the terms θᵀQθ, q·θ and c are each ~10⁶ nats and cancel
+    to the few nats that vary over the posterior, so f32 evaluation left
+    ~8 nats of θ-dependent rounding noise in every FlyMC log density. About
+    ref ≈ argmax, Δᵀ·Q·Δ and q·Δ are small and accurate; the expansion is
+    exact for any ref (q and c absorb it), so ref need only be close.
+    Other bounds' stats are returned unchanged.
+    """
+    if not isinstance(bound, (LogisticBound, StudentTBound)) or (
+        stats.ref is not None
+    ):
+        return stats
+    Q, q, c = stats.Q, stats.q, stats.c
+    ref = -0.5 * jnp.linalg.lstsq(Q, q)[0]
+    Qr = _mm(Q, ref)
+    return CollapsedStats(Q, q + 2.0 * Qr, c + _mm(ref, Qr) + _mm(q, ref), ref)
+
+
+def _vector_quadratic(theta: jax.Array, stats: CollapsedStats) -> jax.Array:
+    """θᵀQθ + q·θ + c, about ``stats.ref`` when set (see :func:`recenter`)."""
+    Q, q, c, ref = stats
+    d = theta if ref is None else theta - ref
+    return _mm(_mm(d, Q), d) + _mm(q, d) + c
 
 
 # ---------------------------------------------------------------------------
@@ -128,6 +184,10 @@ class FusedBound(Bound, Protocol):
 
     def fused_kernel_kwargs(self) -> dict: ...
 
+    def fused_delta(self, theta: jax.Array, data: GLMData) -> jax.Array:
+        """δ = log L - log B by the kernel's own numerics, in plain jnp."""
+        ...
+
 
 def fused_family_of(bound) -> str | None:
     """The bound's fused-kernel family, or None if it must use the jnp path.
@@ -157,6 +217,19 @@ def fused_family_of(bound) -> str | None:
         if effective is not None and not issubclass(declarer, effective):
             return None
     return cls.fused_family
+
+
+def delta(bound, theta: jax.Array, data: GLMData) -> jax.Array:
+    """Per-datum δ_n = log L_n(θ) - log B_n(θ), the jnp engines' one route.
+
+    Fused bounds compute it with the fused kernel's formulas
+    (:mod:`repro.core.numerics`), which keep δ accurate down to the
+    tangency instead of subtracting two nearly equal logs; any other
+    bound falls back to ``log_lik - log_bound``.
+    """
+    if fused_family_of(bound) is not None:
+        return bound.fused_delta(theta, data)
+    return bound.log_lik(theta, data) - bound.log_bound(theta, data)
 
 
 BOUND_REGISTRY: dict[str, type] = {}
@@ -213,31 +286,35 @@ class LogisticBound:
         return {}
 
     @staticmethod
+    def fused_delta(theta: jax.Array, data: GLMData) -> jax.Array:
+        return numerics.logistic_delta(data.t * _mm(data.x, theta), data.xi)
+
+    @staticmethod
     def log_lik(theta: jax.Array, data: GLMData) -> jax.Array:
-        s = data.t * (data.x @ theta)
+        s = data.t * _mm(data.x, theta)
         return -jax.nn.softplus(-s)
 
     @staticmethod
     def log_bound(theta: jax.Array, data: GLMData) -> jax.Array:
-        s = data.t * (data.x @ theta)
+        s = data.t * _mm(data.x, theta)
         return _jj_a(data.xi) * s * s + 0.5 * s + _jj_c(data.xi)
 
     @staticmethod
     def suffstats(data: GLMData) -> CollapsedStats:
         a = _jj_a(data.xi)
         # s² = (θᵀx)² (t²=1), so Q = Σ a_n x xᵀ; the linear term keeps t.
-        Q = jnp.einsum("n,nd,ne->de", a, data.x, data.x)
-        q = 0.5 * jnp.einsum("n,nd->d", data.t.astype(data.x.dtype), data.x)
+        Q = _einsum("n,nd,ne->de", a, data.x, data.x)
+        q = 0.5 * _einsum("n,nd->d", data.t.astype(data.x.dtype), data.x)
         c = jnp.sum(_jj_c(data.xi))
         return CollapsedStats(Q, q, c)
 
     @staticmethod
     def collapsed(theta: jax.Array, stats: CollapsedStats) -> jax.Array:
-        return theta @ stats.Q @ theta + stats.q @ theta + stats.c
+        return _vector_quadratic(theta, stats)
 
     @staticmethod
     def tighten(theta_map: jax.Array, data: GLMData) -> GLMData:
-        return data._replace(xi=jnp.abs(data.x @ theta_map))
+        return data._replace(xi=jnp.abs(_mm(data.x, theta_map)))
 
     @staticmethod
     def default_xi(data: GLMData, xi: float = 1.5) -> GLMData:
@@ -283,13 +360,18 @@ class SoftmaxBound:
         return {}
 
     @staticmethod
+    def fused_delta(theta: jax.Array, data: GLMData) -> jax.Array:
+        eta = _mm(data.x, theta.T)  # (N, K)
+        return numerics.softmax_delta_padded(eta, data.xi, eta.shape[-1])
+
+    @staticmethod
     def log_lik(theta: jax.Array, data: GLMData) -> jax.Array:
-        eta = data.x @ theta.T  # (N, K)
+        eta = _mm(data.x, theta.T)  # (N, K)
         return _softmax_log_lik_eta(eta, data.t)
 
     @staticmethod
     def log_bound(theta: jax.Array, data: GLMData) -> jax.Array:
-        eta = data.x @ theta.T
+        eta = _mm(data.x, theta.T)
         eta0 = data.xi
         K = eta.shape[-1]
         g = jax.nn.one_hot(data.t, K, dtype=eta.dtype) - jax.nn.softmax(eta0)
@@ -307,8 +389,8 @@ class SoftmaxBound:
         K = eta0.shape[-1]
         g = jax.nn.one_hot(t, K, dtype=x.dtype) - jax.nn.softmax(eta0)
         r = g + _a_mul(eta0)  # (N, K)
-        S = jnp.einsum("nd,ne->de", x, x)  # (D, D)
-        R = jnp.einsum("nd,nk->dk", x, r)  # (D, K)
+        S = _einsum("nd,ne->de", x, x)  # (D, D)
+        R = _einsum("nd,nk->dk", x, r)  # (D, K)
         c = jnp.sum(
             _softmax_log_lik_eta(eta0, t)
             - jnp.sum(g * eta0, axis=-1)
@@ -318,14 +400,14 @@ class SoftmaxBound:
 
     @staticmethod
     def collapsed(theta: jax.Array, stats: CollapsedStats) -> jax.Array:
-        S, R, c = stats
-        quad = jnp.sum((_a_mul(theta.T).T @ S) * theta)  # tr(AθSθᵀ)
+        S, R, c, _ = stats
+        quad = jnp.sum(_mm(_a_mul(theta.T).T, S) * theta)  # tr(AθSθᵀ)
         lin = jnp.sum(theta.T * R)  # tr(θR)
         return -0.5 * quad + lin + c
 
     @staticmethod
     def tighten(theta_map: jax.Array, data: GLMData) -> GLMData:
-        return data._replace(xi=data.x @ theta_map.T)
+        return data._replace(xi=_mm(data.x, theta_map.T))
 
     @staticmethod
     def default_xi(data: GLMData, n_classes: int) -> GLMData:
@@ -361,6 +443,11 @@ class StudentTBound:
     def fused_kernel_kwargs(self) -> dict:
         return {"nu": self.nu, "sigma": self.sigma}
 
+    def fused_delta(self, theta: jax.Array, data: GLMData) -> jax.Array:
+        return numerics.student_t_delta(
+            data.t - _mm(data.x, theta), data.xi, self.nu, self.sigma
+        )
+
     def _log_t_const(self, dtype) -> jax.Array:
         nu = self.nu
         return jnp.asarray(
@@ -380,11 +467,11 @@ class StudentTBound:
         return -((self.nu + 1.0) / 2.0) / (self.nu + u)
 
     def log_lik(self, theta: jax.Array, data: GLMData) -> jax.Array:
-        z = (data.t - data.x @ theta) / self.sigma
+        z = (data.t - _mm(data.x, theta)) / self.sigma
         return self._f(z * z)
 
     def log_bound(self, theta: jax.Array, data: GLMData) -> jax.Array:
-        z = (data.t - data.x @ theta) / self.sigma
+        z = (data.t - _mm(data.x, theta)) / self.sigma
         u0 = (data.xi / self.sigma) ** 2
         return self._f(u0) + self._fprime(u0) * (z * z - u0)
 
@@ -392,17 +479,17 @@ class StudentTBound:
         x, y = data.x, data.t
         u0 = (data.xi / self.sigma) ** 2
         A = self._fprime(u0) / (self.sigma**2)  # coefficient of r² (negative)
-        Q = jnp.einsum("n,nd,ne->de", A, x, x)
-        q = -2.0 * jnp.einsum("n,n,nd->d", A, y, x)
+        Q = _einsum("n,nd,ne->de", A, x, x)
+        q = -2.0 * _einsum("n,n,nd->d", A, y, x)
         c = jnp.sum(A * y * y) + jnp.sum(self._f(u0) - self._fprime(u0) * u0)
         return CollapsedStats(Q, q, c)
 
     @staticmethod
     def collapsed(theta: jax.Array, stats: CollapsedStats) -> jax.Array:
-        return theta @ stats.Q @ theta + stats.q @ theta + stats.c
+        return _vector_quadratic(theta, stats)
 
     def tighten(self, theta_map: jax.Array, data: GLMData) -> GLMData:
-        return data._replace(xi=data.t - data.x @ theta_map)
+        return data._replace(xi=data.t - _mm(data.x, theta_map))
 
     @staticmethod
     def default_xi(data: GLMData) -> GLMData:

@@ -34,16 +34,33 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import bounds as bounds_lib
 from repro.core import brightness, samplers
 from repro.core.bounds import CollapsedStats, GLMData
 
 # Numerics are single-sourced in repro.core.numerics (shared with the fused
 # Pallas kernel); log_expm1 stays re-exported here for backward compat.
-from repro.core.numerics import _DELTA_FLOOR, log_expm1  # noqa: F401
+from repro.core.numerics import (  # noqa: F401
+    _DELTA_FLOOR,
+    fixed_order_sum,
+    log_expm1,
+)
 
 
 def _tree_gather(data: GLMData, idx: jax.Array) -> GLMData:
-    return jax.tree.map(lambda a: jnp.take(a, idx, axis=0), data)
+    take = lambda a: jnp.take(a, idx, axis=0)
+    return GLMData(x=take(data.x), t=take(data.t), xi=take(data.xi))
+
+
+def _gather_layout_of(data: GLMData) -> jax.Array:
+    """``data.x_rows``, which ``backend="pallas"`` needs built beforehand."""
+    if data.x_rows is None:
+        raise ValueError(
+            'backend="pallas" reads the features in their gather layout: '
+            "pass the data through bounds.with_gather_layout first "
+            "(api.firefly and dist_algorithm do)"
+        )
+    return data.x_rows
 
 
 # ---------------------------------------------------------------------------
@@ -143,8 +160,8 @@ def make_joint_logpost(
             from repro.kernels.bright_glm.ops import bright_glm
 
             delta, s = bright_glm(
-                data.x, data.t, data.xi, bright_idx, n_bright, theta,
-                family=fam, **kernel_kwargs,
+                _gather_layout_of(data), data.t, data.xi, bright_idx,
+                n_bright, theta, family=fam, **kernel_kwargs,
             )
             for ax in spec.axis_names:
                 s = jax.lax.psum(s, ax)
@@ -160,10 +177,8 @@ def make_joint_logpost(
     rows = _tree_gather(data, bright_idx)
 
     def f(theta: jax.Array):
-        ll = spec.bound.log_lik(theta, rows)
-        lb = spec.bound.log_bound(theta, rows)
-        delta = ll - lb
-        s = jnp.sum(jnp.where(bright_mask, log_expm1(delta), 0.0))
+        delta = bounds_lib.delta(spec.bound, theta, rows)
+        s = fixed_order_sum(jnp.where(bright_mask, log_expm1(delta), 0.0))
         for ax in spec.axis_names:
             s = jax.lax.psum(s, ax)
         lp = spec.log_prior(theta) + spec.bound.collapsed(theta, stats) + s
@@ -192,7 +207,7 @@ def _refresh_sampler(
         (lp, aux), grad = jax.value_and_grad(f, has_aux=True)(theta)
         return samplers.SamplerState(theta, lp, grad, aux), bright.num
     # lp from cached δ — zero new likelihood queries.
-    s = jnp.sum(jnp.where(mask, log_expm1(delta), 0.0))
+    s = fixed_order_sum(jnp.where(mask, log_expm1(delta), 0.0))
     for ax in spec.axis_names:
         s = jax.lax.psum(s, ax)
     lp = spec.log_prior(theta) + spec.bound.collapsed(theta, stats) + s
@@ -262,7 +277,7 @@ def _implicit_z_update(
     mask_c = jnp.arange(spec.cand_capacity) < n_cand
 
     rows = _tree_gather(data, cand_idx)
-    delta_c = spec.bound.log_lik(theta, rows) - spec.bound.log_bound(theta, rows)
+    delta_c = bounds_lib.delta(spec.bound, theta, rows)
     u3 = jnp.take(
         jax.random.uniform(k_db, (n,), delta_full.dtype), cand_idx, mode="clip"
     )
@@ -297,12 +312,12 @@ def _candidate_delta(
 
         fam = fused_family_of(spec.bound)
         delta, _ = bright_glm(
-            data.x, data.t, data.xi, cand_idx, n_cand, theta,
+            _gather_layout_of(data), data.t, data.xi, cand_idx, n_cand, theta,
             family=fam, **spec.bound.fused_kernel_kwargs(),
         )
         return delta
     rows = _tree_gather(data, cand_idx)
-    return spec.bound.log_lik(theta, rows) - spec.bound.log_bound(theta, rows)
+    return bounds_lib.delta(spec.bound, theta, rows)
 
 
 def _fused_z_update(
@@ -398,7 +413,7 @@ def _explicit_z_update(
         jax.random.permutation(k_idx, jnp.arange(n, dtype=jnp.int32)), 0, r
     )
     rows = _tree_gather(data, idx)
-    delta = spec.bound.log_lik(theta, rows) - spec.bound.log_bound(theta, rows)
+    delta = bounds_lib.delta(spec.bound, theta, rows)
     # p(z=1) = (L-B)/L = -expm1(-δ)
     p_bright = -jnp.expm1(-jnp.maximum(delta, _DELTA_FLOOR))
     z_idx = jax.random.uniform(k_z, (r,), delta.dtype) < p_bright

@@ -129,13 +129,14 @@ def make_dist_flymc(bound, log_prior, mesh, n_global: int, **spec_kw):
     spec = flymc.FlyMCSpec(
         bound=bound, log_prior=log_prior, axis_names=axes, **spec_kw
     )
-    data_ps = GLMData(x=PS(axes), t=PS(axes), xi=PS(axes))
-    stats_ps = bounds_lib.CollapsedStats(Q=PS(), q=PS(), c=PS())
+    data_ps = PS(axes)  # every GLMData leaf row-sharded, x_rows included
+    stats_ps = PS()  # replicated, the expansion point included
     state_ps = _state_pspecs(axes)
     stats_out_ps = flymc.StepStats(*([PS()] * 5))
 
     def _stats_local(data):
-        return bounds_lib.psum_stats(bound.suffstats(data), axes)
+        stats = bounds_lib.psum_stats(bound.suffstats(data), axes)
+        return bounds_lib.recenter(bound, stats)
 
     # check_vma=False at every call site below: jax's replication checker is
     # skipped for trace speed, so replicated (PS()) outputs are TRUSTED —
@@ -215,6 +216,8 @@ def dist_algorithm(bound, log_prior, mesh, data: GLMData, **spec_kw):
     from repro.api import SamplingAlgorithm
 
     n_global = data.x.shape[0]
+    if spec_kw.get("backend") == "pallas":
+        data = shard_data(bounds_lib.with_gather_layout(data), mesh)
     # Capacities are PER-SHARD: growth must cap at the shard-local row count,
     # not N — bright_buffer slices the shard-local arr inside shard_map.
     n_local = n_global // mesh.size
@@ -224,12 +227,15 @@ def dist_algorithm(bound, log_prior, mesh, data: GLMData, **spec_kw):
     stats = stats_fn(data)
     axes = tuple(mesh.axis_names)
 
-    def init(key, position):
-        state, _ = init_fn(data, stats, position, key)
+    def init_data(key, position, data_, stats_):
+        state, _ = init_fn(data_, stats_, position, key)
         return state
 
-    def step(key, state):
-        return step_fn(data, stats, state._replace(rng=key))
+    def step_data(key, state, data_, stats_):
+        return step_fn(data_, stats_, state._replace(rng=key))
+
+    init = lambda key, position: init_data(key, position, data, stats)
+    step = lambda key, state: step_data(key, state, data, stats)
 
     grown = []  # memoized so the driver's jit cache sees a stable identity
 
@@ -274,6 +280,12 @@ def dist_algorithm(bound, log_prior, mesh, data: GLMData, **spec_kw):
         init_overflow=_overflow_fn if can_grow else None,
         default_position=jnp.zeros(data.x.shape[-1]),
         spec=spec,
+        # The operand forms: the driver passes the sharded rows in, so no
+        # jit bakes them into its executable.
+        step_data=step_data,
+        init_data=init_data,
+        data=data,
+        stats=stats,
     )
 
 
@@ -300,6 +312,7 @@ def chain_fleet(alg, mesh):
     from repro.api import SamplingAlgorithm
 
     axes = tuple(mesh.axis_names)
+    mesh = _auto_mesh(mesh)
     row = PS(axes)  # leading-axis (chain) sharding, as a pytree prefix
     # check_vma=False on all three fleet shard_maps: trivially sound — every
     # in/out spec is chain-sharded (no PS() output exists to mis-replicate)
@@ -340,6 +353,7 @@ def chain_fleet(alg, mesh):
         step_chains=step_chains,
         init_chains=init_chains,
         step_data=alg.step_data,
+        init_data=alg.init_data,
         step_chains_data=step_chains_data,
         data=alg.data,
         stats=alg.stats,
@@ -350,6 +364,19 @@ def chain_fleet(alg, mesh):
         default_position=alg.default_position,
         spec=alg.spec,
     )
+
+
+def _auto_mesh(mesh):
+    """``mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+    fleet's outputs carry their chain sharding in their types; the
+    driver's collector fold then vmaps chain-sharded chunk outputs
+    against unsharded carries, which jax rejects. The fleet is pure
+    placement, so the sharding stays out of the types.
+    """
+    auto = (jax.sharding.AxisType.Auto,) * len(mesh.axis_names)
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
 
 
 def run_dist_chain(

@@ -1,7 +1,8 @@
 """Pure-jnp oracle for the bright-GLM kernel.
 
 Computes, for a padded buffer of bright indices, the per-datum
-δ_n = log L_n - log B_n and the masked pseudo-log-likelihood contribution
+δ_n = log L_n - log B_n (by the kernel's formulas in
+:mod:`repro.core.numerics`, so only the θᵀx reduction differs) and the masked pseudo-log-likelihood contribution
 log(exp(δ)-1) — the inner loop of every FlyMC θ-update (paper §2, Alg. 1
 line 19). Families: logistic (Jaakkola–Jordan bound), student_t (tangent
 bound) and softmax (Böhning bound); each reduces to a (batched) inner
@@ -36,18 +37,26 @@ def bright_glm_ref(
         t=jnp.take(t, idx, axis=0),
         xi=jnp.take(xi, idx, axis=0),
     )
+    return bright_rows_ref(rows, mask, theta, family=family, nu=nu,
+                           sigma=sigma)
+
+
+def bright_rows_ref(
+    rows: GLMData,  # the C gathered rows
+    mask: jax.Array,  # (C,) validity
+    theta: jax.Array,
+    family: str = "logistic",
+    nu: float = 4.0,
+    sigma: float = 1.0,
+):
+    """:func:`bright_glm_ref` on rows already gathered."""
     if family == "logistic":
-        ll = LogisticBound.log_lik(theta, rows)
-        lb = LogisticBound.log_bound(theta, rows)
+        delta = LogisticBound.fused_delta(theta, rows)
     elif family == "student_t":
-        bound = StudentTBound(nu=nu, sigma=sigma)
-        ll = bound.log_lik(theta, rows)
-        lb = bound.log_bound(theta, rows)
+        delta = StudentTBound(nu=nu, sigma=sigma).fused_delta(theta, rows)
     elif family == "softmax":
-        ll = SoftmaxBound.log_lik(theta, rows)
-        lb = SoftmaxBound.log_bound(theta, rows)
+        delta = SoftmaxBound.fused_delta(theta, rows)
     else:
         raise ValueError(family)
-    delta = ll - lb
     contrib = jnp.where(mask, log_expm1(delta), 0.0)
     return delta, contrib

@@ -62,6 +62,22 @@ def pad_to(d: int, mult: int) -> int:
     return ((d + mult - 1) // mult) * mult
 
 
+def gather_layout(x: jax.Array) -> jax.Array:
+    """(N, D) features → (N, 1, Dp) f32, Dp = D rounded up to 128 lanes.
+
+    The layout the bright-GLM kernel gathers rows from. Mosaic DMAs whole
+    (sublane, lane) tiles only: a plain (N, D) array packs 8 rows per
+    (8, 128) tile, so one row cannot be copied out of it, and a lane
+    extent that is not a multiple of 128 is refused. Here every row owns
+    a (1, Dp) tile, zero past column D. TPU HBM lays an (N, D) f32 array
+    out in (8, 128) tiles too, so this copy takes the bytes per row that
+    the device already spends on ``x``.
+    """
+    d = x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad_to(d, 128) - d)))
+    return xp[:, None, :]
+
+
 def default_interpret() -> bool:
     """Interpret-mode fallback: compile for real only on TPU backends."""
     return jax.default_backend() != "tpu"

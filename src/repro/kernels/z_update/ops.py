@@ -36,12 +36,12 @@ from repro.kernels.z_update.ref import q_threshold_bits
 
 
 @lru_cache(maxsize=None)
-def _pallas_dispatch(n, q_bits, cand_cap_padded, block_rows, interpret):
+def _pallas_dispatch(n, q_bits, cand_rows, block_rows, interpret):
     """The pallas_call dispatch as a ``custom_vmap`` function (memoized on
     the static config): plain call = single-chain kernel; vmap over chains
     = one chain-grid megakernel launch
     (:func:`repro.kernels.common.make_chain_dispatch`)."""
-    kw = dict(n=n, q_bits=q_bits, cand_cap_padded=cand_cap_padded,
+    kw = dict(n=n, q_bits=q_bits, cand_rows=cand_rows,
               block_rows=block_rows, interpret=interpret)
 
     def plain(arr2d, meta):
@@ -82,9 +82,10 @@ def z_candidates(
     meta = jnp.concatenate(
         [jnp.reshape(num.astype(jnp.int32), (1,)), key_words.astype(jnp.int32)]
     )
-    candp = common.pad_to(max(int(cand_capacity), 8), 8)
+    # Whole (8, 128) tiles of candidate slots.
+    cand_rows = 8 * common.pad_to(max(int(cand_capacity), 1), 1024) // 1024
     call = _pallas_dispatch(
-        n, q_threshold_bits(q_db), candp, block_rows, bool(interpret)
+        n, q_threshold_bits(q_db), cand_rows, block_rows, bool(interpret)
     )
     cand, count = call(arr2d, meta)
-    return cand[:cand_capacity, 0], count[0, 0]
+    return cand.reshape(-1)[:cand_capacity], count[0]

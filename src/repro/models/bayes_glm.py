@@ -76,17 +76,23 @@ class GLMModel:
 
     # ---- densities -----------------------------------------------------------
 
-    def full_log_posterior(self, theta: jax.Array) -> jax.Array:
-        """Exact full-data log posterior (the Regular-MCMC target)."""
-        return self.log_prior(theta) + jnp.sum(
-            self.bound.log_lik(theta, self.data)
-        )
+    def full_log_posterior(
+        self, theta: jax.Array, data: GLMData | None = None
+    ) -> jax.Array:
+        """Exact full-data log posterior (the Regular-MCMC target).
 
-    def full_logpdf_fn(self) -> samplers.LogDensityFn:
+        ``data`` defaults to the model's own rows; passing them lets a jitted
+        caller take the dataset as an operand instead of a baked-in constant.
+        """
+        data = self.data if data is None else data
+        return self.log_prior(theta) + jnp.sum(self.bound.log_lik(theta, data))
+
+    def full_logpdf_fn(self, data: GLMData | None = None) -> samplers.LogDensityFn:
         """(lp, aux) wrapper for core.samplers; aux is a dummy scalar."""
 
         def f(theta):
-            return self.full_log_posterior(theta), jnp.zeros((), theta.dtype)
+            lp = self.full_log_posterior(theta, data)
+            return lp, jnp.zeros((), theta.dtype)
 
         return f
 
@@ -102,23 +108,30 @@ class GLMModel:
         """Adam ascent on the full-data log posterior (≈ the paper's SGD)."""
         if theta0 is None:
             theta0 = 0.01 * jax.random.normal(key, self.theta_shape)
-        neg_lp = lambda th: -self.full_log_posterior(th)
-        grad_fn = jax.grad(neg_lp)
 
-        def body(carry, _):
-            th, m, v, t = carry
-            g = grad_fn(th)
-            t = t + 1
-            m = 0.9 * m + 0.1 * g
-            v = 0.999 * v + 0.001 * g * g
-            mh = m / (1.0 - 0.9**t)
-            vh = v / (1.0 - 0.999**t)
-            th = th - lr * mh / (jnp.sqrt(vh) + 1e-8)
-            return (th, m, v, t), None
+        def solve(data, theta0):
+            grad_fn = jax.grad(lambda th: -self.full_log_posterior(th, data))
 
-        init = (theta0, jnp.zeros_like(theta0), jnp.zeros_like(theta0), 0.0)
-        (theta, _, _, _), _ = jax.lax.scan(body, init, None, length=steps)
-        return theta
+            def body(carry, _):
+                th, m, v, t = carry
+                g = grad_fn(th)
+                t = t + 1
+                m = 0.9 * m + 0.1 * g
+                v = 0.999 * v + 0.001 * g * g
+                mh = m / (1.0 - 0.9**t)
+                vh = v / (1.0 - 0.999**t)
+                th = th - lr * mh / (jnp.sqrt(vh) + 1e-8)
+                return (th, m, v, t), None
+
+            zeros = jnp.zeros_like(theta0)
+            (theta, _, _, _), _ = jax.lax.scan(
+                body, (theta0, zeros, zeros, 0.0), None, length=steps
+            )
+            return theta
+
+        # The rows go in as an operand: closed over, they would be baked
+        # into the executable as a constant (a GB at the paper's N = 1.8M).
+        return jax.jit(solve)(self.data, theta0)
 
     def map_tuned(self, theta_map: jax.Array) -> "GLMModel":
         """Retighten bounds at θ_MAP and rebuild suff-stats (one-time cost)."""

@@ -253,13 +253,14 @@ class GroupEngine:
             )
             for name, col in self.colls.items()
         }
-        model = job_lib.build_model(job)
         lane = lambda t: jax.tree.map(lambda l: jnp.asarray(l)[None], t)
         return {
             "states": lane(_raw(states)),
             "keys": jax.random.key_data(chain_keys)[None],
-            "data": lane(model.data),
-            "stats": lane(model.stats),
+            # alg.data, not the model's: for backend="pallas" it carries
+            # the kernel's gather layout, built once here.
+            "data": lane(alg.data),
+            "stats": lane(alg.stats),
             "carries": lane(carries),
             "counts": jnp.zeros((1,), jnp.int32),
         }, over

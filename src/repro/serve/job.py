@@ -241,9 +241,13 @@ def chain_rows(job: Job, alg: SamplingAlgorithm):
                 else alg.default_position)
     if position is None:
         raise ValueError(f"job {job.job_id!r} has no initial position")
+    # The driver's init: the operand form, with the rows passed in.
+    operands = (alg.data, alg.stats)
     if job.num_chains == 1:
-        states = jax.tree.map(lambda l: l[None],
-                              jax.jit(alg.init)(k_init, position))
+        states = jax.tree.map(
+            lambda l: l[None],
+            jax.jit(alg.init_data)(k_init, position, *operands),
+        )
         chain_keys = k_steps[None]
     else:
         init_keys = jax.random.split(k_init, job.num_chains)
@@ -251,6 +255,8 @@ def chain_rows(job: Job, alg: SamplingAlgorithm):
             lambda l: jnp.broadcast_to(l, (job.num_chains,) + jnp.shape(l)),
             position,
         )
-        states = jax.jit(alg.batched_init())(init_keys, positions)
+        states = jax.jit(jax.vmap(alg.init_data, in_axes=(0, 0, None, None)))(
+            init_keys, positions, *operands
+        )
         chain_keys = jax.random.split(k_steps, job.num_chains)
     return states, chain_keys

@@ -101,23 +101,27 @@ def test_chunk_size_invariance(model):
 
 
 def test_capacity_overflow_mid_chain_is_exact(model):
-    """A chain that overflows mid-run (capacity just above the initial
-    bright set) must bitwise match one run at ample capacity throughout:
+    """A chain that overflows at init and mid-run (capacity below the
+    initial bright set, candidate buffer below a step's proposals) must
+    bitwise match one run at ample capacity throughout:
     per-datum RNG makes the trajectory capacity-invariant, and the driver
     re-runs the overflowed chunk from the saved pre-chunk state."""
     key = jax.random.key(9)
 
-    def run(cap):
+    def run(cap, cand_cap):
         alg = api.firefly(
-            model, kernel="rwmh", capacity=cap, cand_capacity=cap,
+            model, kernel="rwmh", capacity=cap, cand_capacity=cand_cap,
             q_db=0.02, step_size=0.1,
         )
         return api.sample(alg, key, 300, chunk_size=32)
 
-    t_small = run(24)
+    # Overflow by construction: the initial bright set (2·q_db·N = 16
+    # expected) exceeds capacity 8, and after init growth the candidate
+    # buffer (2–4 slots) is below the q_db·N ≈ 8 candidates a step proposes.
+    t_small = run(8, 1)
     grown = t_small.algorithm.spec.capacity
-    assert grown > 24, "test must exercise a mid-chain capacity overflow"
-    t_big = run(N)  # full capacity: can never overflow
+    assert grown > 8, "test must exercise a mid-chain capacity overflow"
+    t_big = run(N, N)  # full capacity: can never overflow
     np.testing.assert_array_equal(
         np.asarray(t_small.theta), np.asarray(t_big.theta)
     )

@@ -42,7 +42,7 @@ def _joint_pair(model, capacity=128, kernel="rwmh"):
         state = jax.jit(alg.init)(jax.random.key(1), alg.default_position)
         idx, mask = brightness.bright_buffer(state.bright, capacity)
         fs[backend] = flymc.make_joint_logpost(
-            alg.spec, model.data, model.stats, idx, mask
+            alg.spec, alg.data, model.stats, idx, mask
         )
     return fs["jnp"], fs["pallas"], mask
 

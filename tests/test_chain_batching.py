@@ -62,7 +62,8 @@ def _family_operands(family):
 @pytest.mark.parametrize("family", ["logistic", "student_t", "softmax"])
 def test_bright_glm_batched_matches_vmap_and_loop(family):
     x, t, xi, idx, nb, theta = _family_operands(family)
-    f = lambda i, n, th: bright_glm(x, t, xi, i, n, th, family=family,
+    xr = common.gather_layout(x)
+    f = lambda i, n, th: bright_glm(xr, t, xi, i, n, th, family=family,
                                     interpret=True)
     with common.chain_batching(True):
         d_b, t_b = jax.vmap(f)(idx, nb, theta)
@@ -81,7 +82,8 @@ def test_bright_glm_batched_grads_match():
     whichever dispatch the forward used (the backward is the shared jnp
     reference either way)."""
     x, t, xi, idx, nb, theta = _family_operands("logistic")
-    f = lambda th, i, n: bright_glm(x, t, xi, i, n, th, family="logistic",
+    xr = common.gather_layout(x)
+    f = lambda th, i, n: bright_glm(xr, t, xi, i, n, th, family="logistic",
                                     interpret=True)[1]
     with common.chain_batching(True):
         g_b = jax.vmap(jax.grad(f))(theta, idx, nb)
@@ -130,11 +132,14 @@ def _fused_model(family):
     return GLMModel.logistic(data, prior_scale=2.0, xi=1.5)
 
 
-def _run_fused(model, batched, *, capacity=96, iters=40, chunk=20,
-               q_db=0.05, kernel="rwmh"):
+def _run_fused(model, batched, *, capacity=96, cand_capacity=None, iters=40,
+               chunk=20, q_db=0.05, kernel="rwmh"):
+    if cand_capacity is None:
+        cand_capacity = capacity
     with common.chain_batching(batched):
         alg = api.firefly(
-            model, kernel=kernel, capacity=capacity, cand_capacity=capacity,
+            model, kernel=kernel, capacity=capacity,
+            cand_capacity=cand_capacity,
             q_db=q_db, step_size=0.08, backend="pallas", z_backend="fused",
         )
         return api.sample(alg, jax.random.key(11), iters, num_chains=K,
@@ -159,13 +164,21 @@ def test_fused_multichain_batched_matches_vmap(family):
                               np.asarray(t_b.theta[1]))
 
 
+# Overflow by construction, whatever the seed: at q_db = 0.02 the initial
+# bright set holds 2·q_db·N = 16 points per chain in expectation, twice the
+# bright capacity of 8, so init grows to 16–32; candidates grow in lockstep
+# only to 2–4, while a step proposes q_db·N ≈ 8 of them, so the first chunk
+# overflows and is re-run at doubled capacities.
+_OVERFLOW = dict(capacity=8, cand_capacity=1, q_db=0.02)
+
+
 def test_fused_multichain_overflow_rerun_batched_matches_vmap():
     """Mid-chunk capacity-doubling re-run through the megakernel path lands
     bitwise on the vmap path's trajectory (and both grew)."""
     model = _fused_model("logistic")
-    t_b = _run_fused(model, True, capacity=24, iters=120, chunk=24, q_db=0.02)
-    assert t_b.algorithm.spec.capacity > 24, "must exercise an overflow"
-    t_v = _run_fused(model, False, capacity=24, iters=120, chunk=24, q_db=0.02)
+    t_b = _run_fused(model, True, iters=120, chunk=24, **_OVERFLOW)
+    assert t_b.algorithm.spec.capacity > 8, "must exercise an overflow"
+    t_v = _run_fused(model, False, iters=120, chunk=24, **_OVERFLOW)
     assert t_v.algorithm.spec.capacity == t_b.algorithm.spec.capacity
     np.testing.assert_array_equal(np.asarray(t_b.theta), np.asarray(t_v.theta))
 
@@ -189,9 +202,8 @@ def test_overflow_rerun_reuses_fold_executable():
 
     model = _fused_model("logistic")
     driver_lib._JIT_CACHE.clear()
-    trace = _run_fused(model, True, capacity=24, iters=120, chunk=24,
-                       q_db=0.02)
-    assert trace.algorithm.spec.capacity > 24  # the run really overflowed
+    trace = _run_fused(model, True, iters=120, chunk=24, **_OVERFLOW)
+    assert trace.algorithm.spec.capacity > 8  # the run really overflowed
     folds = [k for k in driver_lib._JIT_CACHE if k[0] == "fold"]
     scans = [k for k in driver_lib._JIT_CACHE if k[0] == "scan"]
     assert len(folds) == 1, folds  # one fold serves every capacity

@@ -230,20 +230,23 @@ def test_all_collectors_bitwise_invariant_to_capacity_overflow(model):
     result must be bitwise the ample-capacity one."""
     key = jax.random.key(9)
 
-    def run(cap):
+    def run(cap, cand_cap):
         alg = api.firefly(
-            model, kernel="rwmh", capacity=cap, cand_capacity=cap,
+            model, kernel="rwmh", capacity=cap, cand_capacity=cand_cap,
             q_db=0.02, step_size=0.1,
         )
         return api.sample(
             alg, key, 300, chunk_size=32, collectors=_all_builtins(model)
         )
 
-    t_small = run(24)
-    assert t_small.algorithm.spec.capacity > 24, (
+    # Overflow by construction: the initial bright set (2·q_db·N = 16
+    # expected) exceeds capacity 8, and after init growth the candidate
+    # buffer (2–4 slots) is below the q_db·N ≈ 8 candidates a step proposes.
+    t_small = run(8, 1)
+    assert t_small.algorithm.spec.capacity > 8, (
         "test must exercise a mid-chain capacity overflow"
     )
-    t_big = run(N)  # full capacity: can never overflow
+    t_big = run(N, N)  # full capacity: can never overflow
     small, big = t_small.results, t_big.results
     assert small.keys() == big.keys()
     for name in small:

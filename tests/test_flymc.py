@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import brightness, flymc
+from repro.core import bounds, brightness, flymc
 from repro.data import logistic_data
 from repro.models.bayes_glm import GLMModel, run_regular_mcmc
 
@@ -128,9 +128,8 @@ def test_explicit_z_update_law_without_replacement(model):
         jax.random.permutation(k_idx, jnp.arange(n, dtype=jnp.int32))[:r]
     )
     assert len(np.unique(idx)) == r  # without replacement
-    delta = model.bound.log_lik(theta, model.data) - model.bound.log_bound(
-        theta, model.data
-    )
+    # δ by the engines' own route (the bound's cancellation-free formulas).
+    delta = bounds.delta(model.bound, theta, model.data)
     p_bright = -jnp.expm1(-jnp.maximum(delta[idx], 1e-10))
     z_exp = np.asarray(z0).copy()
     z_exp[idx] = np.asarray(

@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.common import gather_layout
+
 jax.config.update("jax_platform_name", "cpu")
 
 RNG = np.random.default_rng(0)
@@ -46,7 +48,7 @@ def test_bright_glm(n, d, c, nb, family):
     idx = jnp.asarray(RNG.choice(n, c, replace=False).astype(np.int32))
     mask = jnp.arange(c) < nb
 
-    delta, total = bright_glm(x, t, xi, idx, jnp.int32(nb), theta, family=family)
+    delta, total = bright_glm(gather_layout(x), t, xi, idx, jnp.int32(nb), theta, family=family)
     d_ref, c_ref = bright_glm_ref(x, t, xi, idx, mask, theta, family=family)
     np.testing.assert_allclose(delta, d_ref, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(total, c_ref.sum(), rtol=1e-4, atol=1e-5)
@@ -64,7 +66,7 @@ def test_bright_glm_grad_matches_ref(family):
     mask = jnp.arange(c) < nb
 
     def f_pallas(th):
-        delta, total = bright_glm(x, t, xi, idx, jnp.int32(nb), th,
+        delta, total = bright_glm(gather_layout(x), t, xi, idx, jnp.int32(nb), th,
                                   family=family)
         return total, delta
 
@@ -97,7 +99,7 @@ def test_bright_glm_full_capacity_padded_buffer():
         # implicit z-update's candidate buffer does
         idx = jnp.asarray(np.where(np.arange(c) < nb, perm, n))
         mask = jnp.arange(c) < nb
-        delta, total = bright_glm(x, t, xi, idx, jnp.int32(nb), theta)
+        delta, total = bright_glm(gather_layout(x), t, xi, idx, jnp.int32(nb), theta)
         d_ref, c_ref = bright_glm_ref(x, t, xi, idx, mask, theta)
         assert np.all(np.isfinite(np.asarray(delta)))
         np.testing.assert_allclose(
@@ -115,7 +117,7 @@ def test_bright_glm_ragged_c_not_multiple_of_block_rows():
     x, t, xi, theta = _glm_case("student_t", n, d)
     idx = jnp.asarray(RNG.choice(n, c, replace=False).astype(np.int32))
     mask = jnp.arange(c) < nb
-    delta, total = bright_glm(x, t, xi, idx, jnp.int32(nb), theta,
+    delta, total = bright_glm(gather_layout(x), t, xi, idx, jnp.int32(nb), theta,
                               family="student_t")
     d_ref, c_ref = bright_glm_ref(x, t, xi, idx, mask, theta,
                                   family="student_t")

@@ -208,16 +208,19 @@ def test_fused_chain_overflow_rerun_is_exact(model):
     chunk at doubled capacity and land bitwise on the ample-capacity
     trajectory — apply_flips' arr is capacity-invariant, so the fused
     engine keeps the driver's exactness contract."""
-    def run(cap):
+    def run(cap, cand_cap):
         alg = api.firefly(
-            model, kernel="rwmh", capacity=cap, cand_capacity=cap,
+            model, kernel="rwmh", capacity=cap, cand_capacity=cand_cap,
             q_db=0.02, step_size=0.1, z_backend="fused",
         )
         return api.sample(alg, jax.random.key(9), 300, chunk_size=32)
 
-    t_small = run(24)
-    assert t_small.algorithm.spec.capacity > 24, "must exercise an overflow"
-    t_big = run(N)
+    # Overflow by construction: the initial bright set (2·q_db·N = 16
+    # expected) exceeds capacity 8, and after init growth the candidate
+    # buffer (2–4 slots) is below the q_db·N ≈ 8 candidates a step proposes.
+    t_small = run(8, 1)
+    assert t_small.algorithm.spec.capacity > 8, "must exercise an overflow"
+    t_big = run(N, N)
     np.testing.assert_array_equal(
         np.asarray(t_small.theta), np.asarray(t_big.theta)
     )
