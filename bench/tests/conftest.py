@@ -1,0 +1,12 @@
+"""The benchmark's self-tests run on the CPU, by hand:
+
+    python -m pytest bench/tests
+"""
+
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
