@@ -102,9 +102,7 @@ def bench(n=5000, d=21, capacity=1024, iters=300, q_db=0.01, reps=3):
         if backend == "pallas":
             record[backend]["hbm_bytes_terms"] = bmodel["pallas_terms"]
     # A compiled-vs-interpreted ratio is not a kernel-speed comparison:
-    # record it only when the pallas numbers come from a real TPU compile
-    # (same null-when-meaningless policy as driver_overhead's
-    # host_overhead_ratio).
+    # record it only when the pallas numbers come from a real TPU compile.
     record["us_per_step_ratio"] = (
         None if interpret
         else record["jnp"]["us_per_step"] / record["pallas"]["us_per_step"]

@@ -46,19 +46,6 @@ def main() -> None:
             f"speedup={r.speedup:.2f}"
         )
 
-    # --- driver overhead (writes BENCH_flymc.json) -------------------------
-    from benchmarks.driver_overhead import main as bench_driver
-
-    rec = bench_driver(quick=args.quick)
-    ov_ratio = rec["host_overhead_ratio"]
-    rows.append(
-        f"driver/scan,{rec['scan_driver']['us_per_step']:.1f},"
-        f"legacy_us={rec['legacy_host_loop']['us_per_step']:.1f};"
-        f"us_ratio={rec['us_per_step_ratio']:.2f};"
-        f"overhead_ratio="
-        f"{'n/a' if ov_ratio is None else f'{ov_ratio:.2f}'}"
-    )
-
     # --- θ-update backend: jnp vs fused pallas kernel ----------------------
     from benchmarks.bright_glm import main as bench_backend
 

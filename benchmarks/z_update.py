@@ -139,7 +139,7 @@ def bench(n=5000, d=21, capacity=1024, iters=300, q_db=0.01, reps=3):
         bmodel["jnp"]["total"] / bmodel["fused"]["total"]
     )
     # Interpret-mode wall times are not kernel speed — null the ratio there,
-    # same policy as bright_glm_backend / driver_overhead.
+    # same policy as bright_glm_backend.
     record["us_per_z_phase_ratio"] = (
         None if interpret
         else record["jnp"]["us_per_z_phase"] / record["fused"]["us_per_z_phase"]

@@ -401,18 +401,21 @@ def regular_mcmc(
         )
 
     def step_with(density, key, state):
-        kern = samplers.bind(kernel, density, kernel_params)
-        new, info = kern(key, state.sampler, jnp.exp(state.log_step))
-        log_step = state.log_step
-        if adapt_target is not None:
-            # Warmup-only (see flymc_step): adapt-forever would mean the
-            # post-warmup chain never follows a fixed Markov kernel.
-            adapted = samplers.adapt_step_size(
-                log_step, info.accept_prob, adapt_target, state.iteration
-            )
-            log_step = jnp.where(
-                state.iteration < num_warmup, adapted, log_step
-            )
+        # The named scope labels the step's ops for a device trace, like
+        # flymc_step's phases.
+        with jax.named_scope("regular.theta"):
+            kern = samplers.bind(kernel, density, kernel_params)
+            new, info = kern(key, state.sampler, jnp.exp(state.log_step))
+            log_step = state.log_step
+            if adapt_target is not None:
+                # Warmup-only (see flymc_step): adapt-forever would mean the
+                # post-warmup chain never follows a fixed Markov kernel.
+                adapted = samplers.adapt_step_size(
+                    log_step, info.accept_prob, adapt_target, state.iteration
+                )
+                log_step = jnp.where(
+                    state.iteration < num_warmup, adapted, log_step
+                )
         out = MCMCState(new, log_step, state.iteration + 1)
         stats = StepStats(
             n_bright=n,

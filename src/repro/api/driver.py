@@ -28,10 +28,20 @@ the one an infinite-capacity sampler would have produced. Collector carries
 only ever fold *committed* chunks (the fold runs after the overflow check
 passes), so every streamed reduction is bitwise capacity/chunk-invariant
 too — with no carry rollback needed.
+
+Tracing: the host's work per chunk runs under the :data:`SPANS`, which a
+``jax.profiler`` trace records beside the device's ops, and is counted,
+always, in :class:`DriverCounters` (``ChunkEvent.driver``,
+``Trace.driver``). The chunk program's ops carry named scopes: the step's
+phases (``flymc.*``, ``regular.theta``), ``driver.outputs`` and, in the
+fold program, ``driver.fold``.
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
+import time
 from collections import OrderedDict
 from typing import Any, NamedTuple
 
@@ -63,6 +73,60 @@ _JIT_CACHE_MAX = 64
 # The back-compat default collector set: one shared instance so repeated
 # sample() calls without collectors= hit the same compiled chunk fn.
 _DEFAULT_TRACE = collectors_lib.FullTrace()
+
+# Host spans of a sample() call (jax.profiler.TraceAnnotation), on the
+# profiler's clock with the device's ops: set-up, then per chunk the call
+# into the chunk program (a retrace or compile lands here), the overflow
+# read (the host waiting on the device), the grow-and-resize before an
+# overflow re-run, the collector fold and the on_chunk hook, then the
+# results.
+SPANS = (
+    SPAN_INIT, SPAN_DISPATCH, SPAN_WAIT, SPAN_REGROW, SPAN_FOLD,
+    SPAN_ON_CHUNK, SPAN_FINALIZE,
+) = (
+    "repro.sample.init", "repro.sample.dispatch", "repro.sample.wait",
+    "repro.sample.regrow", "repro.sample.fold", "repro.sample.on_chunk",
+    "repro.sample.finalize",
+)
+
+
+@dataclasses.dataclass
+class DriverCounters:
+    """Cumulative host-side counts of one sample() call's chunk loop.
+
+    chunks       committed chunks
+    reruns       chunk re-runs after a capacity overflow
+    rerun_iters  chain-iterations thrown away by those re-runs
+    dispatch_s   seconds calling into the chunk program (asynchronous: the
+                 host enqueues, unless it traces or compiles)
+    wait_s       seconds reading the overflow flag: the host waiting on the
+                 device
+    regrow_s     seconds growing the algorithm and resizing the state
+    fold_s       seconds dispatching the collector fold
+    hook_s       seconds in the on_chunk hook
+    """
+
+    chunks: int = 0
+    reruns: int = 0
+    rerun_iters: int = 0
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
+    regrow_s: float = 0.0
+    fold_s: float = 0.0
+    hook_s: float = 0.0
+
+
+@contextlib.contextmanager
+def _timed(counters: DriverCounters, field: str, span: str):
+    """Run the block under host span ``span``; add its seconds to
+    ``counters.<field>``."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(span):
+            yield
+    finally:
+        setattr(counters, field,
+                getattr(counters, field) + time.perf_counter() - t0)
 
 
 def _cached(key, build):
@@ -144,6 +208,7 @@ class Trace(NamedTuple):
     algorithm     : the (possibly capacity-grown) SamplingAlgorithm
     results       : {name: finalized result} for the ``collectors=`` dict
                     passed in; None on the default (FullTrace) path
+    driver        : the call's final :class:`DriverCounters`
     """
 
     theta: jax.Array | None
@@ -152,6 +217,7 @@ class Trace(NamedTuple):
     final_state: Any
     algorithm: SamplingAlgorithm
     results: dict | None = None
+    driver: DriverCounters | None = None
 
 
 def _broadcast_positions(position, num_chains: int, reference):
@@ -236,11 +302,13 @@ def _make_scan_fn(alg: SamplingAlgorithm, num_chains: int, cs: int):
     def chunk(state, keys, start, *operands):
         def body(carry, i):
             new_state, info = step(fold_keys(keys, i), carry, *operands)
-            return new_state, (position(new_state), info)
+            with jax.named_scope("driver.outputs"):
+                return new_state, (position(new_state), info)
 
         iters = start + jnp.arange(cs, dtype=jnp.int32)
         final, (pos, infos) = jax.lax.scan(body, state, iters)
-        return final, pos, infos, jnp.any(infos.overflow)
+        with jax.named_scope("driver.outputs"):
+            return final, pos, infos, jnp.any(infos.overflow)
 
     return jax.jit(chunk)
 
@@ -251,16 +319,20 @@ class ChunkEvent:
     ``start``/``size`` locate the chunk (``start`` counts committed samples
     before it, so ``start + size`` is the total committed so far);
     ``num_samples`` is the run's target; ``state`` the post-chunk chain
-    state. ``peek(name)`` reads the named collector's would-be result
+    state; ``driver`` a snapshot of the call's :class:`DriverCounters` at
+    this boundary (the hook's own time is added after it returns).
+    ``peek(name)`` reads the named collector's would-be result
     through :func:`repro.api.collectors.peek` — non-destructive, never
     aliasing the live carry, so peeking cannot perturb the run.
     """
 
-    def __init__(self, start, size, num_samples, state, colls, carries, multi):
+    def __init__(self, start, size, num_samples, state, colls, carries, multi,
+                 driver=None):
         self.start = start
         self.size = size
         self.num_samples = num_samples
         self.state = state
+        self.driver = driver
         self._colls = colls
         self._carries = carries
         self._multi = multi
@@ -321,7 +393,8 @@ def make_collector_fold(colls: dict, multi: bool, max_count: int | None = None):
                 p, inf = x
                 return {n: updates[n](cars[n], p, inf) for n in names}, None
 
-            cars, _ = jax.lax.scan(body, carries, (pos, infos))
+            with jax.named_scope("driver.fold"):
+                cars, _ = jax.lax.scan(body, carries, (pos, infos))
             return cars
 
     else:
@@ -343,7 +416,10 @@ def make_collector_fold(colls: dict, multi: bool, max_count: int | None = None):
                 cars = jax.tree.map(sel, new, cars)
                 return (cars, cnt + active.astype(cnt.dtype)), None
 
-            (cars, cnt), _ = jax.lax.scan(body, (carries, counts), (pos, infos))
+            with jax.named_scope("driver.fold"):
+                (cars, cnt), _ = jax.lax.scan(
+                    body, (carries, counts), (pos, infos)
+                )
             return cars, cnt
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
@@ -429,6 +505,175 @@ def sample(
         colls = collectors_lib.validate_collectors(collectors)
         default_path = False
 
+    counters = DriverCounters()
+    with jax.profiler.TraceAnnotation(SPAN_INIT):
+        alg, state, start_offset, k_steps = _initial_state(
+            alg, key, num_chains, init_position, init_state
+        )
+        chain_keys = (
+            jax.random.split(k_steps, num_chains) if multi else k_steps
+        )
+        # Collector carries, built from shape/dtype structs only (no
+        # compute): one carry per chain, broadcast over the leading chain
+        # axis.
+        pos_struct, stats_struct = alg.output_structs(
+            jax.tree.map(
+                lambda l: jax.ShapeDtypeStruct(
+                    jnp.shape(l)[1:] if multi else jnp.shape(l), l.dtype
+                ),
+                state,
+            )
+        )
+        carries = {
+            name: col.init(num_samples, pos_struct, stats_struct)
+            for name, col in colls.items()
+        }
+        if multi:
+            carries = jax.tree.map(
+                lambda l: jnp.broadcast_to(l, (num_chains,) + l.shape),
+                carries,
+            )
+
+    def scan_fn_for(alg, cs):
+        # Keyed on (num_chains, chunk_size, capacity) plus the step/dispatch
+        # identities: an overflow re-run at a grown capacity traces its own
+        # entry, and a later sample() call that reaches the same capacity
+        # (memoized alg.grow() → same step identity) reuses it.
+        return _cached(
+            ("scan", alg.step, alg.step_chains, alg.position, num_chains,
+             cs, _capacity_of(alg), kernels_common.chain_batching_enabled(),
+             alg.step_data, alg.step_chains_data),
+            lambda: _make_scan_fn(alg, num_chains, cs),
+        )
+
+    # Capacity-independent on purpose: chunk outputs are (cs, K) θ/stats
+    # with no buffer-shaped operand, so one fold serves every capacity and
+    # an overflow retry never recompiles it.
+    fold_fn = _cached(
+        ("fold", tuple(colls.items()), multi),
+        lambda: make_collector_fold(colls, multi),
+    )
+
+    def scan_operands(alg):
+        threads = _threads_data(alg) or (multi and _threads_data_chains(alg))
+        return (alg.data, alg.stats) if threads else ()
+
+    start = 0
+    while start < num_samples:
+        cs = min(chunk_size, num_samples - start)
+        # Keep the pre-chunk state alive for the exact re-run on overflow.
+        prev = state
+        with _timed(counters, "dispatch_s", SPAN_DISPATCH):
+            final, pos, infos, overflow = scan_fn_for(alg, cs)(
+                state, chain_keys, jnp.int32(start_offset + start),
+                *scan_operands(alg)
+            )
+        while _overflowed(counters, overflow):
+            counters.reruns += 1
+            counters.rerun_iters += num_chains * cs
+            with _timed(counters, "regrow_s", SPAN_REGROW):
+                alg = _grown(alg)
+                resize = alg.resize if alg.resize is not None else _identity
+                prev = _cached(
+                    ("resize", resize, multi),
+                    lambda: jax.jit(jax.vmap(resize) if multi else resize),
+                )(prev)
+            with _timed(counters, "dispatch_s", SPAN_DISPATCH):
+                final, pos, infos, overflow = scan_fn_for(alg, cs)(
+                    prev, chain_keys, jnp.int32(start_offset + start),
+                    *scan_operands(alg)
+                )
+        if health_check:
+            floats = [pos] + [
+                l for l in jax.tree.leaves((infos, final))
+                if jnp.issubdtype(l.dtype, jnp.floating)
+            ]
+            ok = _cached(
+                ("health", len(floats)),
+                lambda: jax.jit(lambda ls: jnp.all(
+                    jnp.stack([jnp.all(jnp.isfinite(l)) for l in ls])
+                )),
+            )(floats)
+            if not bool(jax.device_get(ok)):
+                raise NonFiniteError(
+                    f"non-finite chain state in iterations "
+                    f"[{start_offset + start}, {start_offset + start + cs}); "
+                    f"committed prefix of {start} samples is intact"
+                )
+        # Only a committed (non-overflowed) chunk reaches the collectors, so
+        # capacity re-runs never need a carry rollback; the donated carry is
+        # updated in place on backends with input-output aliasing.
+        if colls:
+            with _timed(counters, "fold_s", SPAN_FOLD):
+                carries = fold_fn(carries, pos, infos)
+        state = final
+        start += cs
+        counters.chunks += 1
+        if on_chunk is not None:
+            event = ChunkEvent(start - cs, cs, num_samples, state, colls,
+                               carries, multi, dataclasses.replace(counters))
+            with _timed(counters, "hook_s", SPAN_ON_CHUNK):
+                stop = on_chunk(event)
+            if stop:
+                break
+
+    committed = start
+
+    with jax.profiler.TraceAnnotation(SPAN_FINALIZE):
+        # finalize() always sees a leading (num_chains, ...) carry axis.
+        if not multi:
+            carries = jax.tree.map(lambda l: l[None], carries)
+        results = {
+            name: colls[name].finalize(carries[name]) for name in colls
+        }
+
+        if default_path:
+            tr = results["trace"]
+            theta, stats = tr["theta"], tr["stats"]
+            if committed < num_samples:  # on_chunk stopped the run early
+                theta = theta[:, :committed]
+                stats = jax.tree.map(lambda l: l[:, :committed], stats)
+            if thin > 1:
+                theta = theta[:, thin - 1 :: thin]
+            total_queries = int(
+                np.asarray(
+                    jax.device_get(stats.lik_queries), dtype=np.int64
+                ).sum()
+            )
+            results = None
+        else:
+            theta = stats = None
+            total_queries = next(
+                (
+                    results[name]
+                    for name, col in colls.items()
+                    if isinstance(col, collectors_lib.QueryBudget)
+                ),
+                None,
+            )
+    return Trace(
+        theta=theta,
+        stats=stats,
+        total_queries=total_queries,
+        final_state=state,
+        algorithm=alg,
+        results=results,
+        driver=counters,
+    )
+
+
+def _overflowed(counters, overflow) -> bool:
+    """Read a chunk's overflow flag: the chunk's one host sync, where the
+    host waits for the device (under the wait span and ``wait_s``)."""
+    with _timed(counters, "wait_s", SPAN_WAIT):
+        return bool(jax.device_get(overflow))
+
+
+def _initial_state(alg, key, num_chains, init_position, init_state):
+    """The chains' starting state, for :func:`sample`: ``init_state``
+    resumed, or ``alg``'s init at ``init_position``, growing ``alg`` until
+    the state fits. Returns (alg, state, iteration offset, step key)."""
+    multi = num_chains > 1
     start_offset = 0
     if init_state is not None:
         state = init_state
@@ -539,140 +784,7 @@ def sample(
                 state = init_fn(alg)(init_keys, positions)
             else:
                 state = init_fn(alg)(k_init, position)
-
-    chain_keys = jax.random.split(k_steps, num_chains) if multi else k_steps
-
-    # Collector carries, built from shape/dtype structs only (no compute):
-    # one carry per chain, broadcast over the leading chain axis.
-    pos_struct, stats_struct = alg.output_structs(
-        jax.tree.map(
-            lambda l: jax.ShapeDtypeStruct(
-                jnp.shape(l)[1:] if multi else jnp.shape(l), l.dtype
-            ),
-            state,
-        )
-    )
-    carries = {
-        name: col.init(num_samples, pos_struct, stats_struct)
-        for name, col in colls.items()
-    }
-    if multi:
-        carries = jax.tree.map(
-            lambda l: jnp.broadcast_to(l, (num_chains,) + l.shape), carries
-        )
-
-    def scan_fn_for(alg, cs):
-        # Keyed on (num_chains, chunk_size, capacity) plus the step/dispatch
-        # identities: an overflow re-run at a grown capacity traces its own
-        # entry, and a later sample() call that reaches the same capacity
-        # (memoized alg.grow() → same step identity) reuses it.
-        return _cached(
-            ("scan", alg.step, alg.step_chains, alg.position, num_chains,
-             cs, _capacity_of(alg), kernels_common.chain_batching_enabled(),
-             alg.step_data, alg.step_chains_data),
-            lambda: _make_scan_fn(alg, num_chains, cs),
-        )
-
-    # Capacity-independent on purpose: chunk outputs are (cs, K) θ/stats
-    # with no buffer-shaped operand, so one fold serves every capacity and
-    # an overflow retry never recompiles it.
-    fold_fn = _cached(
-        ("fold", tuple(colls.items()), multi),
-        lambda: make_collector_fold(colls, multi),
-    )
-
-    def scan_operands(alg):
-        threads = _threads_data(alg) or (multi and _threads_data_chains(alg))
-        return (alg.data, alg.stats) if threads else ()
-
-    start = 0
-    while start < num_samples:
-        cs = min(chunk_size, num_samples - start)
-        # Keep the pre-chunk state alive for the exact re-run on overflow.
-        prev = state
-        final, pos, infos, overflow = scan_fn_for(alg, cs)(
-            state, chain_keys, jnp.int32(start_offset + start),
-            *scan_operands(alg)
-        )
-        while bool(jax.device_get(overflow)):  # the chunk's one host sync
-            alg = _grown(alg)
-            resize = alg.resize if alg.resize is not None else _identity
-            prev = _cached(
-                ("resize", resize, multi),
-                lambda: jax.jit(jax.vmap(resize) if multi else resize),
-            )(prev)
-            final, pos, infos, overflow = scan_fn_for(alg, cs)(
-                prev, chain_keys, jnp.int32(start_offset + start),
-                *scan_operands(alg)
-            )
-        if health_check:
-            floats = [pos] + [
-                l for l in jax.tree.leaves((infos, final))
-                if jnp.issubdtype(l.dtype, jnp.floating)
-            ]
-            ok = _cached(
-                ("health", len(floats)),
-                lambda: jax.jit(lambda ls: jnp.all(
-                    jnp.stack([jnp.all(jnp.isfinite(l)) for l in ls])
-                )),
-            )(floats)
-            if not bool(jax.device_get(ok)):
-                raise NonFiniteError(
-                    f"non-finite chain state in iterations "
-                    f"[{start_offset + start}, {start_offset + start + cs}); "
-                    f"committed prefix of {start} samples is intact"
-                )
-        # Only a committed (non-overflowed) chunk reaches the collectors, so
-        # capacity re-runs never need a carry rollback; the donated carry is
-        # updated in place on backends with input-output aliasing.
-        if colls:
-            carries = fold_fn(carries, pos, infos)
-        state = final
-        start += cs
-        if on_chunk is not None and on_chunk(
-            ChunkEvent(start - cs, cs, num_samples, state, colls, carries,
-                       multi)
-        ):
-            break
-
-    committed = start
-
-    # finalize() always sees a leading (num_chains, ...) carry axis.
-    if not multi:
-        carries = jax.tree.map(lambda l: l[None], carries)
-    results = {name: colls[name].finalize(carries[name]) for name in colls}
-
-    if default_path:
-        tr = results["trace"]
-        theta, stats = tr["theta"], tr["stats"]
-        if committed < num_samples:  # on_chunk stopped the run early
-            theta = theta[:, :committed]
-            stats = jax.tree.map(lambda l: l[:, :committed], stats)
-        if thin > 1:
-            theta = theta[:, thin - 1 :: thin]
-        total_queries = int(
-            np.asarray(jax.device_get(stats.lik_queries), dtype=np.int64).sum()
-        )
-        results = None
-    else:
-        theta = stats = None
-        total_queries = next(
-            (
-                results[name]
-                for name, col in colls.items()
-                if isinstance(col, collectors_lib.QueryBudget)
-            ),
-            None,
-        )
-    return Trace(
-        theta=theta,
-        stats=stats,
-        total_queries=total_queries,
-        final_state=state,
-        algorithm=alg,
-        results=results,
-    )
-
+    return alg, state, start_offset, k_steps
 
 def _grown(alg: SamplingAlgorithm) -> SamplingAlgorithm:
     if alg.grow is None:

@@ -368,25 +368,31 @@ def _fused_z_update(
     log_q = jnp.log(jnp.asarray(spec.q_db, delta_full.dtype))
 
     # --- bright → dark (free: cached δ + O(C) counter uniforms) ------------
-    idx_b, mask_b = brightness.bright_buffer(bright, spec.capacity)
-    u1 = counter_uniform(kw, DRAW_DARKEN, idx_b)
-    darken = mask_b & (jnp.log(u1) + log_expm1(delta_bright) < log_q)
+    with jax.named_scope("flymc.z.delta"):
+        idx_b, mask_b = brightness.bright_buffer(bright, spec.capacity)
+        u1 = counter_uniform(kw, DRAW_DARKEN, idx_b)
+        darken = mask_b & (jnp.log(u1) + log_expm1(delta_bright) < log_q)
 
     # --- dark → bright (streamed selection, then O(cand) work) -------------
-    cand_idx, n_cand = z_candidates(
-        bright.arr, bright.num, kw, spec.q_db, spec.cand_capacity
-    )
-    overflow_c = n_cand > spec.cand_capacity
-    mask_c = jnp.arange(spec.cand_capacity, dtype=jnp.int32) < n_cand
-    nb = jnp.minimum(n_cand, spec.cand_capacity)
-    delta_c = _candidate_delta(spec, data, theta, cand_idx, nb)
-    u3 = counter_uniform(kw, DRAW_BRIGHT, jnp.clip(cand_idx, 0, n - 1))
-    brighten = mask_c & (jnp.log(u3) + log_q < log_expm1(delta_c))
-    delta_full = delta_full.at[cand_idx].set(
-        jnp.where(mask_c, delta_c, delta_full[jnp.clip(cand_idx, 0, n - 1)]),
-        mode="drop",
-    )
-    bright_new = brightness.apply_flips(bright, darken, cand_idx, brighten)
+    with jax.named_scope("flymc.z.candidates"):
+        cand_idx, n_cand = z_candidates(
+            bright.arr, bright.num, kw, spec.q_db, spec.cand_capacity
+        )
+    with jax.named_scope("flymc.z.delta"):
+        overflow_c = n_cand > spec.cand_capacity
+        mask_c = jnp.arange(spec.cand_capacity, dtype=jnp.int32) < n_cand
+        nb = jnp.minimum(n_cand, spec.cand_capacity)
+        delta_c = _candidate_delta(spec, data, theta, cand_idx, nb)
+        u3 = counter_uniform(kw, DRAW_BRIGHT, jnp.clip(cand_idx, 0, n - 1))
+        brighten = mask_c & (jnp.log(u3) + log_q < log_expm1(delta_c))
+    with jax.named_scope("flymc.z.flips"):
+        delta_full = delta_full.at[cand_idx].set(
+            jnp.where(mask_c, delta_c,
+                      delta_full[jnp.clip(cand_idx, 0, n - 1)]),
+            mode="drop",
+        )
+        bright_new = brightness.apply_flips(bright, darken, cand_idx,
+                                            brighten)
     return bright_new, delta_full, n_cand, overflow_c
 
 
@@ -435,6 +441,13 @@ def flymc_step(
 ) -> tuple[FlyMCState, StepStats]:
     """θ-update followed by z-update (paper §2 alternation).
 
+    The phases run under named scopes, which label the compiled ops (HLO
+    op-name metadata) so a device trace splits the step's time by phase:
+    ``flymc.theta`` (bright buffer, θ-kernel, δ-cache scatter), ``flymc.z``
+    (the z-update; the fused engine adds ``flymc.z.candidates``,
+    ``flymc.z.delta`` and ``flymc.z.flips`` inside it) and
+    ``flymc.refresh`` (sampler refresh and step-size adaptation).
+
     Distributed (spec.axis_names non-empty, inside shard_map): the θ-kernel
     runs replicated with identical keys on every shard (identical proposals
     and accept decisions; likelihood sums are psum'd inside the joint), while
@@ -446,60 +459,66 @@ def flymc_step(
         key_z = jax.random.fold_in(key_z, jax.lax.axis_index(ax))
 
     # ---- θ | z -------------------------------------------------------------
-    idx, mask = brightness.bright_buffer(state.bright, spec.capacity)
-    f = make_joint_logpost(spec, data, stats, idx, mask)
-    kernel = samplers.bind(spec.kernel, f, spec.kernel_kwargs)
-    new_sampler, info = kernel(key_theta, state.sampler, jnp.exp(state.log_step))
-    queries_theta = info.n_evals * state.bright.num
-    # δ at (possibly) new θ for the bright buffer, from the kernel's aux cache.
-    delta_full = state.delta_full.at[idx].set(
-        jnp.where(mask, new_sampler.aux, state.delta_full[idx])
-    )
+    with jax.named_scope("flymc.theta"):
+        idx, mask = brightness.bright_buffer(state.bright, spec.capacity)
+        f = make_joint_logpost(spec, data, stats, idx, mask)
+        kernel = samplers.bind(spec.kernel, f, spec.kernel_kwargs)
+        new_sampler, info = kernel(
+            key_theta, state.sampler, jnp.exp(state.log_step)
+        )
+        queries_theta = info.n_evals * state.bright.num
+        # δ at (possibly) new θ for the bright buffer, from the kernel's aux
+        # cache.
+        delta_full = state.delta_full.at[idx].set(
+            jnp.where(mask, new_sampler.aux, state.delta_full[idx])
+        )
 
     # ---- z | θ -------------------------------------------------------------
-    if spec.mode == "implicit" and spec.z_backend == "fused":
-        bright_new, delta_full, queries_z, overflow_c = _fused_z_update(
-            spec, data, key_z, new_sampler.theta, state.bright, delta_full,
-            new_sampler.aux,
-        )
-    elif spec.mode == "implicit":
-        z_new, delta_full, queries_z, overflow_c = _implicit_z_update(
-            spec, data, key_z, new_sampler.theta, state.bright, delta_full,
-            new_sampler.aux,
-        )
-        bright_new = brightness.from_z(z_new)
-    elif spec.z_backend == "fused":
-        raise ValueError(
-            "z_backend='fused' requires mode='implicit' (Algorithm 1's "
-            "explicit Gibbs resampling re-evaluates a dense subset, so "
-            "there is no sparse candidate stream to fuse)"
-        )
-    else:
-        z_new, delta_full, queries_z, overflow_c = _explicit_z_update(
-            spec, data, key_z, new_sampler.theta, state.bright, delta_full
-        )
-        bright_new = brightness.from_z(z_new)
-    overflow = overflow_c | (bright_new.num > spec.capacity)
-    if spec.axis_names:
-        overflow = jax.lax.pmax(overflow.astype(jnp.int32),
-                                spec.axis_names).astype(bool)
+    with jax.named_scope("flymc.z"):
+        if spec.mode == "implicit" and spec.z_backend == "fused":
+            bright_new, delta_full, queries_z, overflow_c = _fused_z_update(
+                spec, data, key_z, new_sampler.theta, state.bright,
+                delta_full, new_sampler.aux,
+            )
+        elif spec.mode == "implicit":
+            z_new, delta_full, queries_z, overflow_c = _implicit_z_update(
+                spec, data, key_z, new_sampler.theta, state.bright,
+                delta_full, new_sampler.aux,
+            )
+            bright_new = brightness.from_z(z_new)
+        elif spec.z_backend == "fused":
+            raise ValueError(
+                "z_backend='fused' requires mode='implicit' (Algorithm 1's "
+                "explicit Gibbs resampling re-evaluates a dense subset, so "
+                "there is no sparse candidate stream to fuse)"
+            )
+        else:
+            z_new, delta_full, queries_z, overflow_c = _explicit_z_update(
+                spec, data, key_z, new_sampler.theta, state.bright,
+                delta_full,
+            )
+            bright_new = brightness.from_z(z_new)
+        overflow = overflow_c | (bright_new.num > spec.capacity)
+        if spec.axis_names:
+            overflow = jax.lax.pmax(overflow.astype(jnp.int32),
+                                    spec.axis_names).astype(bool)
 
-    refreshed, extra_q = _refresh_sampler(
-        spec, data, stats, new_sampler.theta, bright_new, delta_full
-    )
-
-    log_step = state.log_step
-    if spec.adapt_target is not None:
-        # Adaptation is WARMUP-ONLY: a kernel whose step size keeps moving
-        # is not a fixed Markov kernel, so the post-warmup chain would lose
-        # detailed balance (diminishing or not). Freeze bitwise after
-        # spec.num_warmup iterations.
-        adapted = samplers.adapt_step_size(
-            log_step, info.accept_prob, spec.adapt_target, state.iteration
+    with jax.named_scope("flymc.refresh"):
+        refreshed, extra_q = _refresh_sampler(
+            spec, data, stats, new_sampler.theta, bright_new, delta_full
         )
-        log_step = jnp.where(
-            state.iteration < spec.num_warmup, adapted, log_step
-        )
+        log_step = state.log_step
+        if spec.adapt_target is not None:
+            # Adaptation is WARMUP-ONLY: a kernel whose step size keeps
+            # moving is not a fixed Markov kernel, so the post-warmup chain
+            # would lose detailed balance (diminishing or not). Freeze
+            # bitwise after spec.num_warmup iterations.
+            adapted = samplers.adapt_step_size(
+                log_step, info.accept_prob, spec.adapt_target, state.iteration
+            )
+            log_step = jnp.where(
+                state.iteration < spec.num_warmup, adapted, log_step
+            )
 
     new_state = FlyMCState(
         sampler=refreshed,
