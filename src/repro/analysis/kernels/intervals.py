@@ -18,11 +18,11 @@ kernels:
   multiplies, min/max/clamp, and reductions have exact transfer functions.
 * ``pl.when`` lowers to ``cond`` whose predicate we recognize when it is a
   conjunction of direct comparisons — the taken branch refines the
-  compared operand (this proves the z-update's guarded candidate store:
-  ``slot`` is only written under ``slot < cand_cap``).
+  compared operand (this proves the z-update's guarded row store:
+  row ``r`` is only written under ``r < cand_rows``).
 * ``fori_loop`` lowers to ``while``; carries are solved by a small inner
   fixpoint with widening, refined through the loop condition (this bounds
-  the extraction counter ``j ∈ [0, cnt_tile - 1]``).
+  the z-update's placement counter ``g ∈ [0, n_out - 1]``).
 
 Mutable refs (accumulators, scratch) are handled by a store-join fixpoint
 across whole-kernel passes with widening: each ref's abstract *content* is

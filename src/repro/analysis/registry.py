@@ -620,8 +620,9 @@ def _kernel_bright_chains() -> Report:
 
 
 # z-update shapes: large enough that the row-block grid axis really
-# revisits the candidate accumulators (4096 ids = 4 blocks of 8×128).
-_ZN = 4096
+# revisits the candidate accumulators (16384 ids = 2 tiles of 64×128,
+# the tile that ops.tile_rows gives at this N).
+_ZN = 16384
 
 
 def _z_fn():

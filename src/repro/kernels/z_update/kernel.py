@@ -22,10 +22,31 @@ with ONE streamed pass over the partition array:
   * selected datum ids are compacted in-kernel into a lane-dense
     ``(cand_rows, 128)`` output buffer: TPU grid steps run sequentially,
     so the buffer and an SMEM running count are race-free accumulators
-    (the same trick as ``bright_glm``'s total). Within a tile
-    the expected candidate count is ``q_db · block`` (≈ 10 for the default
-    tile), so extraction loops ``fori_loop``-many times over a masked
-    argmin — O(candidates) reductions, not O(block²) scatter matrices.
+    (the same trick as ``bright_glm``'s total).
+
+Compaction is vector work whose cost does not depend on how many
+candidates a tile holds — no loop over candidates, no reduction to a
+scalar per candidate:
+
+  * **rank** — an inclusive prefix count of the candidate mask in
+    row-major position order: log-step lane rolls within each row, then
+    log-step sublane rolls over the row totals. Candidate k of the tile
+    goes to slot ``base + k`` (``base`` = the running count);
+  * **move** — the tile is staged below ``_PAD_ROWS`` empty rows whose
+    first row stands for output row ``base // 128``, so slot ``base + k``
+    is staged position ``base % 128 + k`` and every candidate moves
+    toward the front by a non-negative distance. The moves are made bit
+    by bit, least significant first (one flat-order roll per bit); since
+    the distances never decrease along the tile, two candidates never
+    meet, and the candidates leave the network in slot order;
+  * **place** — each staged row that holds a candidate is one whole output
+    row, stored under a lane mask: ``ceil((base % 128 + cnt) / 128)``
+    masked row stores per tile, one or two at q_db · block ≈ 82.
+
+Tile size follows from N (:func:`repro.kernels.z_update.ops.tile_rows`):
+a tile's cost no longer scales with its candidates, so the largest tile
+of up to 64 rows (8,192 datums) that pads N by at most 1/16 is the
+cheapest.
 
 Chain batching: the grid's LEADING dimension is ``num_chains`` — one
 launch streams every chain's partition array back to back, and the
@@ -54,6 +75,34 @@ from repro.core.numerics import DRAW_CAND, threefry2x32
 
 _LANES = 128
 _UNIFORM_SHIFT = 8  # int32 >> 8 (logical) = 24-bit uniform lanes
+_PAD_ROWS = 8  # empty staged rows ahead of the tile: one whole sublane tile
+
+
+def _pull(x: jax.Array, step: int) -> jax.Array:
+    """``y[p] = x[p + step]`` in row-major flat order, wrapping at the end."""
+    rows = x.shape[0]
+    if step % _LANES == 0:
+        return pltpu.roll(x, rows - step // _LANES, 0)
+    rot = pltpu.roll(x, _LANES - step, 1)  # rot[r, l] = x[r, (l + step) % 128]
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(col >= _LANES - step, pltpu.roll(rot, rows - 1, 0), rot)
+
+
+def _prefix(x: jax.Array, axis: int) -> jax.Array:
+    """Inclusive prefix sum of an int32 2-D array along ``axis``."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    s = 1
+    while s < x.shape[axis]:
+        x = x + jnp.where(idx >= s, pltpu.roll(x, s, axis), 0)
+        s *= 2
+    return x
+
+
+def _inclusive_count(m: jax.Array) -> jax.Array:
+    """Row-major inclusive prefix sum of an int32 (rows, 128) array."""
+    within = _prefix(m, 1)
+    totals = jnp.broadcast_to(within[:, _LANES - 1:], m.shape)
+    return within + _prefix(totals, 0) - totals
 
 
 def z_candidates_pallas_chains(
@@ -62,7 +111,7 @@ def z_candidates_pallas_chains(
     n: int,  # true datum count (ids >= n are padding)
     q_bits: int,  # candidate threshold: bits24 < q_bits ⇔ u < q_db
     cand_rows: int,  # output buffer rows of 128 slots (multiple of 8)
-    block_rows: int = 8,
+    block_rows: int,  # tile rows (multiple of 8)
     interpret: bool = False,
 ):
     """Returns (cand (K, cand_rows, 128) int32 padded with n, count (K, 1)).
@@ -79,14 +128,16 @@ def z_candidates_pallas_chains(
     Mosaic stores no scalars to VMEM.
     """
     k_chains, rows, lanes = arr3d.shape
-    assert lanes == _LANES and rows % block_rows == 0, arr3d.shape
+    br = block_rows
+    assert lanes == _LANES and br % 8 == 0 and rows % br == 0, (
+        arr3d.shape, br)
     assert meta.shape == (k_chains, 3), meta.shape
     assert cand_rows % 8 == 0, cand_rows
-    br = block_rows
-    slots = cand_rows * _LANES
+    staged_rows = _PAD_ROWS + br
+    # Every move distance is below staged_rows · 128: one network pass per bit.
+    steps = [1 << b for b in range((staged_rows * _LANES - 1).bit_length())]
 
-    def kernel(meta_ref, arr_ref, cand_ref, count_ref):
-        none = jnp.int32(2**30)  # position key of a slot already extracted
+    def kernel(meta_ref, arr_ref, cand_ref, count_ref, staged_ref):
         ch = pl.program_id(0)
         i = pl.program_id(1)
         num = meta_ref[ch, 0]
@@ -106,31 +157,45 @@ def z_candidates_pallas_chains(
         bits24 = jax.lax.shift_right_logical(bits, _UNIFORM_SHIFT)
         cand = (pos >= num) & (pos < n) & (bits24 < q_bits)
 
-        cnt_tile = jnp.sum(cand.astype(jnp.int32))
+        m = cand.astype(jnp.int32)
+        cnt_tile = jnp.sum(m)
         base = count_ref[ch, 0]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
-
-        def extract(j, keyed):
-            # j-th candidate of this tile = the least live position. The
-            # carry is int32 (positions, `none` once taken): Mosaic does
-            # not carry boolean vectors through a loop.
-            p = jnp.min(keyed)
-            hit = keyed == p
-            datum = jnp.sum(jnp.where(hit, tile, 0))
-            slot = base + j
-
-            @pl.when(slot < slots)
-            def _store():
-                r = jax.lax.div(slot, _LANES)  # slot ≥ 0: floor division
-                old = cand_ref[0, pl.ds(r, 1), :]
-                cand_ref[0, pl.ds(r, 1), :] = jnp.where(
-                    lane == jax.lax.rem(slot, _LANES), datum, old
-                )
-
-            return jnp.where(hit, none, keyed)
-
-        jax.lax.fori_loop(0, cnt_tile, extract, jnp.where(cand, pos, none))
         count_ref[ch, 0] = base + cnt_tile
+
+        @pl.when(cnt_tile > 0)
+        def _compact():
+            off = jax.lax.rem(base, _LANES)  # base ≥ 0
+            staged_pos = (row + _PAD_ROWS) * _LANES + col
+            # Distance to the front; -1 marks a staged position with no
+            # candidate (every bit set, so the move test checks >= 0).
+            dist = jnp.where(cand, staged_pos - off - _inclusive_count(m) + 1,
+                             -1)
+            empty = jnp.full((_PAD_ROWS, _LANES), -1, jnp.int32)
+            dist = jnp.concatenate([empty, dist], axis=0)
+            ids = jnp.concatenate([empty, tile], axis=0)
+            for step in steps:
+                d_in, ids_in = _pull(dist, step), _pull(ids, step)
+                move = (d_in >= 0) & ((d_in & step) != 0)
+                stay = (dist >= 0) & ((dist & step) == 0)
+                dist = jnp.where(move, d_in, jnp.where(stay, dist, -1))
+                ids = jnp.where(move, ids_in, ids)
+            staged_ref[...] = jnp.where(dist >= 0, ids, -1)
+
+            first = jax.lax.div(base, _LANES)
+            n_out = jax.lax.div(off + cnt_tile + _LANES - 1, _LANES)
+
+            def place(g, carry):
+                r = first + g
+
+                @pl.when(r < cand_rows)
+                def _store():
+                    got = staged_ref[pl.ds(g, 1), :]
+                    old = cand_ref[0, pl.ds(r, 1), :]
+                    cand_ref[0, pl.ds(r, 1), :] = jnp.where(got >= 0, got, old)
+
+                return carry
+
+            jax.lax.fori_loop(0, n_out, place, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # meta
@@ -140,6 +205,7 @@ def z_candidates_pallas_chains(
             pl.BlockSpec((1, cand_rows, _LANES), lambda ch, i, *_: (ch, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),  # count: whole (K, 1)
         ],
+        scratch_shapes=[pltpu.VMEM((staged_rows, _LANES), jnp.int32)],
     )
     return pl.pallas_call(
         kernel,
@@ -164,7 +230,7 @@ def z_candidates_pallas(
     n: int,  # true datum count (ids >= n are padding)
     q_bits: int,  # candidate threshold: bits24 < q_bits ⇔ u < q_db
     cand_rows: int,  # output buffer rows of 128 slots (multiple of 8)
-    block_rows: int = 8,
+    block_rows: int,  # tile rows (multiple of 8)
     interpret: bool = False,
 ):
     """Single-chain entry point: the ``num_chains == 1`` case of

@@ -35,6 +35,19 @@ from repro.kernels.z_update.kernel import (
 from repro.kernels.z_update.ref import q_threshold_bits
 
 
+def tile_rows(n: int) -> int:
+    """Rows of 128 datums per grid step: the largest of 64, 32, 16 and 8
+    whose padding of ``n`` is at most ``n // 16`` (8 when none is).
+
+    The kernel's cost per tile no longer grows with the candidates it
+    holds, so the fewest, largest tiles win until padding adds work."""
+    for rows in (64, 32, 16):
+        block = rows * 128
+        if common.pad_to(max(n, block), block) - n <= n // 16:
+            return rows
+    return 8
+
+
 @lru_cache(maxsize=None)
 def _pallas_dispatch(n, q_bits, cand_rows, block_rows, interpret):
     """The pallas_call dispatch as a ``custom_vmap`` function (memoized on
@@ -59,7 +72,6 @@ def z_candidates(
     key_words: jax.Array,  # (2,) int32 counter-RNG key words (step key)
     q_db: float,
     cand_capacity: int,
-    block_rows: int = 8,
     interpret: bool | None = None,
 ):
     """Fused dark→bright candidate selection. Returns (cand_idx, n_cand).
@@ -67,13 +79,15 @@ def z_candidates(
     ``cand_idx`` is (cand_capacity,) int32 in arr-position order, padded
     with the sentinel ``N``; ``n_cand`` is the true candidate count (it may
     exceed ``cand_capacity``, in which case the caller must raise the
-    overflow flag). ``interpret=None`` auto-selects interpret mode off-TPU.
+    overflow flag). The tile is :func:`tile_rows` of N. ``interpret=None``
+    auto-selects interpret mode off-TPU.
     Under ``jax.vmap`` over the chain axis the dispatch batches into a
     single chain-grid megakernel (see :mod:`repro.kernels.common`).
     """
     if interpret is None:
         interpret = common.default_interpret()
     n = arr.shape[0]
+    block_rows = tile_rows(n)
     block = block_rows * 128
     p = common.pad_to(max(n, block), block)
     arr2d = jnp.pad(

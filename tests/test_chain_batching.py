@@ -93,27 +93,33 @@ def test_bright_glm_batched_grads_match():
 
 
 def test_z_candidates_batched_matches_vmap_and_loop():
+    """At N = 997 (one 8-row tile) and N = 12,214 (tiles of 32 rows)."""
     from repro.core import brightness
+    from repro.kernels.z_update.ops import tile_rows
 
-    arrs, nums, kws = [], [], []
-    for c in range(K):
-        z0 = jax.random.bernoulli(jax.random.key(c), 0.15 * (c + 1), (997,))
-        st = brightness.from_z(z0)
-        arrs.append(jnp.pad(st.arr, (0, 0)))
-        nums.append(st.num)
-        kws.append(numerics.key_words_of(jax.random.key(40 + c)))
-    arrs, nums, kws = jnp.stack(arrs), jnp.stack(nums), jnp.stack(kws)
-    f = lambda a, n, k: z_candidates(a, n, k, 0.05, 64, interpret=True)
-    with common.chain_batching(True):
-        c_b, n_b = jax.vmap(f)(arrs, nums, kws)
-    with common.chain_batching(False):
-        c_v, n_v = jax.vmap(f)(arrs, nums, kws)
-    np.testing.assert_array_equal(np.asarray(c_b), np.asarray(c_v))
-    np.testing.assert_array_equal(np.asarray(n_b), np.asarray(n_v))
-    for c in range(K):
-        c_1, n_1 = f(arrs[c], nums[c], kws[c])
-        np.testing.assert_array_equal(np.asarray(c_b[c]), np.asarray(c_1))
-        assert int(n_b[c]) == int(n_1)
+    assert tile_rows(12214) > 8
+    for size in (997, 12214):
+        arrs, nums, kws = [], [], []
+        for c in range(K):
+            z0 = jax.random.bernoulli(jax.random.key(c), 0.15 * (c + 1),
+                                      (size,))
+            st = brightness.from_z(z0)
+            arrs.append(st.arr)
+            nums.append(st.num)
+            kws.append(numerics.key_words_of(jax.random.key(40 + c)))
+        arrs, nums, kws = jnp.stack(arrs), jnp.stack(nums), jnp.stack(kws)
+        f = lambda a, n, k: z_candidates(a, n, k, 0.05, 64, interpret=True)
+        with common.chain_batching(True):
+            c_b, n_b = jax.vmap(f)(arrs, nums, kws)
+        with common.chain_batching(False):
+            c_v, n_v = jax.vmap(f)(arrs, nums, kws)
+        np.testing.assert_array_equal(np.asarray(c_b), np.asarray(c_v))
+        np.testing.assert_array_equal(np.asarray(n_b), np.asarray(n_v))
+        for c in range(K):
+            c_1, n_1 = f(arrs[c], nums[c], kws[c])
+            np.testing.assert_array_equal(np.asarray(c_b[c]),
+                                          np.asarray(c_1))
+            assert int(n_b[c]) == int(n_1)
 
 
 # ---------------------------------------------------------------------------

@@ -339,8 +339,9 @@ def test_bright_race_classification_pin():
 
 def test_z_race_classification_pin():
     """z-update: candidate buffer AND count both accumulate across the
-    row-block sweep (grid axis 1)."""
-    call = _first_call(registry._z_fn(), registry._s((4096,), jnp.int32),
+    row-block sweep (grid axis 1), at an N of two tiles."""
+    call = _first_call(registry._z_fn(),
+                       registry._s((registry._ZN,), jnp.int32),
                        registry._s((), jnp.int32),
                        registry._s((2,), jnp.int32))
     classes = classify_outputs(call)

@@ -73,6 +73,11 @@ def test_threefry_matches_jax_prng_bits():
     (1000, 1.0, 0.5, 64),     # all bright — no candidates
     (997, 0.3, 0.1, 128),     # N not a multiple of the tile
     (64, 0.5, 0.3, 16),       # N smaller than one tile
+    (5000, 0.0, 0.5, 3000),   # ≈ 512 candidates a tile: several output rows
+    (3000, 0.1, 0.1, 512),    # a tile's candidates straddle an output row
+    (12214, 0.1, 0.01, 256),  # derived tile of 32 rows
+    (16000, 0.05, 0.02, 512),  # derived tile of 64 rows
+    (5000, 0.0, 0.5, 300),    # buffer overflow inside a many-row tile
 ])
 def test_z_candidates_kernel_matches_ref(n, num_frac, q_db, cap):
     from repro.kernels.z_update.ops import z_candidates
@@ -85,6 +90,20 @@ def test_z_candidates_kernel_matches_ref(n, num_frac, q_db, cap):
     c_r, n_r = z_candidates_ref(st.arr, st.num, kw, q_db, cap)
     assert int(n_k) == int(n_r)
     np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_r))
+
+
+@pytest.mark.parametrize("n,rows", [
+    (1_800_000, 64), (16000, 64), (12214, 32), (6000, 16), (5000, 8),
+    (1000, 8), (64, 8),
+])
+def test_z_tile_rows_follow_n(n, rows):
+    """The largest tile of up to 64 rows that pads N by at most N/16."""
+    from repro.kernels.common import pad_to
+    from repro.kernels.z_update.ops import tile_rows
+
+    assert tile_rows(n) == rows
+    block = rows * 128
+    assert rows == 8 or pad_to(n, block) - n <= n // 16
 
 
 def test_z_candidates_parity_under_jit_and_capacity():
