@@ -14,6 +14,18 @@ harness needs about it is found by name:
 The program is used through its normal path only: ``GLMModel`` (MAP fit
 and tuned bound), ``repro.api.firefly``/``regular_mcmc`` and
 ``repro.api.sample`` with an ``on_chunk`` hook.
+
+θ may have any shape. The harness flattens every θ it reads once, in the
+program's row-major order, to P = prod(θ's shape): the window's draws to
+(chains, iterations, P), a kept state's θ to (P,) per chain, the θs of
+``bright_gap`` to (M, P). The reference (``bench/reference/__init__.py``)
+takes θ flat and may name the coordinates that are compared
+(``coords``); ESS, ``mean_z`` and ``sd_gap`` are taken over those.
+
+A traced run reads the device time of every named scope the program puts
+on its ops (``bench/scopes.py``) and, on every run, the window's
+difference of the driver's counters (``ChunkEvent.driver``): the metric
+readers find them as ``ctx.scopes`` and ``ctx.window["driver"]``.
 """
 
 from __future__ import annotations
@@ -52,6 +64,11 @@ BUFFER_MARGIN = 1.5
 # What a traffic file's "bound" and "start" may say.
 BOUNDS = ("map", "regular")
 STARTS = ("map", "dark")
+# The driver's counters that are host work (the overflow wait, wait_s, is
+# the device's).
+HOST_PARTS = ("dispatch_s", "regrow_s", "fold_s", "hook_s")
+# Entries of each list of a traced line's breakdown.
+BREAKDOWN_TOP = 10
 
 
 def load_json(path):
@@ -160,6 +177,12 @@ def window_buffer(rate, seconds, chunk):
     return chunk * 2 ** math.ceil(math.log2(chunks))
 
 
+def counter_delta(first, last):
+    """Field-by-field difference of two DriverCounters snapshots."""
+    a, b = dataclasses.asdict(first), dataclasses.asdict(last)
+    return {k: b[k] - a[k] for k in a}
+
+
 class Window:
     """The ``on_chunk`` hook that times the window.
 
@@ -167,7 +190,9 @@ class Window:
     trace and compiles its fold) starts the window; the first boundary at
     least ``seconds`` later ends it. Each boundary waits for the chunk's
     state before reading the clock. A few boundary states, drawn from the
-    seed, are kept for the checks.
+    seed, are kept for the checks, and at every boundary the clock, the
+    driver's counters (``ChunkEvent.driver``) and the seconds the hook
+    spent starting or stopping the profiler.
     """
 
     def __init__(self, seconds, trace_seconds, trace_dir, keep, rng,
@@ -185,6 +210,7 @@ class Window:
         self.tracing = None  # open TraceAnnotation while the profiler runs
         self.trace_range = None  # (committed at start, committed at stop)
         self.trace_t0 = None
+        self.marks = []  # [(clock, DriverCounters, profiler seconds)]
 
     def __call__(self, event):
         import jax
@@ -193,15 +219,19 @@ class Window:
             jax.block_until_ready(event.state)
             now = time.perf_counter()
             self.boundaries += 1
+            profiler_s = 0.0
             if self.boundaries == 1:
                 self.t0, self.n0 = now, event.committed
                 self.counter.active = True
                 if self.trace_dir is not None:
-                    self._start_trace(event.committed)
+                    profiler_s = self._timed(self._start_trace,
+                                             event.committed)
+                self._mark(event, now, profiler_s)
                 return False
             if self.tracing is not None and (
                     now - self.trace_t0 >= self.trace_seconds):
-                self._stop_trace(event.committed)
+                profiler_s = self._timed(self._stop_trace, event.committed)
+            self._mark(event, now, profiler_s)
             # Reservoir sample of the window's boundary states.
             seen = self.boundaries - 1
             if len(self.kept) < self.keep:
@@ -215,6 +245,40 @@ class Window:
                 self.counter.active = False
                 return True
             return False
+
+    def _mark(self, event, now, profiler_s):
+        driver = getattr(event, "driver", None)
+        if driver is not None:
+            self.marks.append((now, driver, profiler_s))
+
+    @staticmethod
+    def _timed(fn, *args):
+        t = time.perf_counter()
+        fn(*args)
+        return time.perf_counter() - t
+
+    def driver(self):
+        """The driver's counters over the window, or None where the
+        program gives none: the difference between the first and the last
+        boundary's, ``profiler_s`` (the seconds that starting and stopping
+        the profiler took inside it, 0 untraced) and its longest stretch
+        between two boundaries, split into the overflow wait and the
+        host's work.
+
+        The counters of a boundary are taken before its hook runs, so the
+        window holds the hooks of every boundary but its last.
+        """
+        if len(self.marks) < 2:
+            return None
+        out = counter_delta(self.marks[0][1], self.marks[-1][1])
+        out["profiler_s"] = sum(p for _, _, p in self.marks[:-1])
+        steps = [(t1 - t0, counter_delta(a, b)) for (t0, a, _), (t1, b, _)
+                 in zip(self.marks, self.marks[1:])]
+        longest, d = max(steps, key=lambda s: s[0])
+        out["longest_boundary_s"] = longest
+        out["longest_wait_s"] = d["wait_s"]
+        out["longest_host_s"] = sum(d[k] for k in HOST_PARTS)
+        return out
 
     def _start_trace(self, committed):
         import jax
@@ -248,13 +312,14 @@ class Fault:
     and the calibration of limits: "frozen" (the step returns its state
     unchanged), "half" (odd rows replaced by their even neighbours: half
     the batch left out, the sum taken as twice the rest's), "shift" (each
-    θ the step produces is reported moved by ``5/√N`` in coordinate 0) or,
-    for FlyMC, "z_frozen" (the step skips its z-update: the bright set
-    stays as it started)."""
+    θ the step produces is reported moved by ``5/√N`` in its flat
+    coordinate 0, the first element in row-major order) or, for FlyMC,
+    "z_frozen" (the step skips its z-update: the bright set stays as it
+    started). ``theta_ndim`` is the number of θ's axes."""
 
     kind: str
 
-    def plant(self, alg, n):
+    def plant(self, alg, n, theta_ndim):
         import jax
         import jax.numpy as jnp
 
@@ -291,8 +356,9 @@ class Fault:
         if self.kind == "shift":
             shift = 5.0 / math.sqrt(n)
             pos = alg.position_of
+            first = (Ellipsis,) + (0,) * theta_ndim
             return dataclasses.replace(
-                alg, position=lambda s: pos(s).at[..., 0].add(shift))
+                alg, position=lambda s: pos(s).at[first].add(shift))
         raise ValueError(f"unknown fault {self.kind!r}")
 
 
@@ -349,7 +415,7 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
         else:
             alg = api.regular_mcmc(tuned, **common)
         if fault is not None:
-            alg = fault.plant(alg, cfg["n"])
+            alg = fault.plant(alg, cfg["n"], theta_map.ndim)
         start = dict(init_position=theta_map)
         if start_at == "dark":
             # Every array goes in as an operand: one baked in as a constant
@@ -387,6 +453,7 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
         win.n_end = out.theta.shape[1]
     window_s = win.t_end - win.t0
     setup_s = win.t0 - t_start
+    driver = win.driver()
     log(f"window: {win.n_end - win.n0} iterations x {chains} chains in "
         f"{window_s:.3f} s; compilations={counter.builds} "
         f"tracings={counter.traces}")
@@ -398,7 +465,7 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
 
     # ---- what the window produced, to the host -------------------------
     n0, n1 = win.n0, win.n_end
-    draws = np.asarray(out.theta[:, n0:n1], np.float64)
+    draws = flat_theta(out.theta[:, n0:n1], 2)
     queries = np.asarray(out.stats.lik_queries[:, n0:n1], np.int64)
     bright = None
     if is_flymc:
@@ -410,7 +477,7 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
     kept = [_state_to_host(s, is_flymc) for _, s in states]
     x = np.asarray(data["x"])
     t = np.asarray(data["t"])
-    theta_star = np.asarray(theta_map)
+    theta_star = np.asarray(theta_map).reshape(-1)
     traced = None
     if win.trace_range is not None:
         a, b = win.trace_range
@@ -419,12 +486,12 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
                                             np.int64).sum())}
     del out, warm, alg, model, tuned, data, start, win.kept
 
-    ess = ess_per_coord(draws)
+    ess = ess_per_coord(coordinates(ref, cfg, draws))
     min_ess = float(ess.min())
     chain_iters = chains * (n1 - n0)
     window = {"chain_iters": chain_iters, "seconds": window_s,
               "min_ess": min_ess,
-              "queries_per_iter": float(queries.mean())}
+              "queries_per_iter": float(queries.mean()), "driver": driver}
 
     # ---- checks --------------------------------------------------------
     log(f"checks: outputs on the host at {time.perf_counter() - t_start:.2f} s")
@@ -452,45 +519,100 @@ def run(cell, seed, seconds, trace=False, *, t_start=None, cfg_over=None,
                 result["metrics"][m["name"]] = {"value": e2e[m["name"]],
                                                 "unit": m["unit"]}
     else:
-        from bench import trace as trace_lib
-
-        kernels = [p.name[:-len(".py")] for p in (BENCH / "work").glob("*.py")
-                   if p.name not in ("__init__.py", "step.py")]
-        _, reduced = trace_lib.reduce_trace(trace_dir, kernels)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        try:
+            reduced, by_scope = reduce_traced(trace_dir)
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
         peaks = load_json(BENCH / "peaks.json")
         if dev.device_kind not in peaks:
             raise KeyError(f"no peaks for device kind {dev.device_kind!r} "
                            "in bench/peaks.json")
         ctx = SimpleNamespace(cfg=cfg, traffic=traffic, flymc=is_flymc,
-                              trace=reduced, traced=traced, window=window,
-                              peaks=peaks[dev.device_kind])
-        for m in spec["per_layer"]:
-            if cell not in m.get("workloads", [cell]):
-                continue
-            value = _module("metrics", m["name"]).read(ctx)
-            if value is not None:
-                result["metrics"][m["name"]] = {"value": float(value),
-                                                "unit": m["unit"]}
+                              trace=reduced, scopes=by_scope, traced=traced,
+                              window=window, peaks=peaks[dev.device_kind])
+        result["metrics"] = per_layer(spec, cell, ctx)
         device["busy_s"] = reduced["busy_ns"] * 1e-9
         device["window_s"] = reduced["window_ns"] * 1e-9
-        result["breakdown"] = {
-            "device_ops": [[n, s * 1e-9] for n, s in reduced["top_ops"]],
-            "idle_gaps": [[n, s * 1e-9] for n, s in reduced["top_gaps"]],
-        }
+        result["breakdown"] = breakdown(reduced, by_scope)
     result["window"] = {"iterations": n1 - n0, "chains": chains,
                         "traced_chain_iters": traced and traced["chain_iters"],
                         "seconds": window_s, "min_ess": min_ess,
                         "compilations": counter.builds,
-                        "tracings": counter.traces}
+                        "tracings": counter.traces, "driver": driver}
     result["checks"] = {k: {"value": v, "limit": limits[k]}
                         for k, v in checks.items()}
     return result
 
 
+def reduce_traced(trace_dir):
+    """(``bench.trace`` reduction with each kernel of ``bench/work``,
+    ``bench.scopes`` reduction) of a recorded trace."""
+    from bench import scopes
+    from bench import trace as trace_lib
+
+    kernels = [p.name[:-len(".py")] for p in (BENCH / "work").glob("*.py")
+               if p.name not in ("__init__.py", "step.py")]
+    _, reduced = trace_lib.reduce_trace(trace_dir, kernels)
+    return reduced, scopes.reduce_trace(trace_dir)
+
+
+def per_layer(spec, cell, ctx):
+    """{metric: {"value", "unit"}} of the cell's per-layer metrics that
+    their readers find something to read for."""
+    out = {}
+    for m in spec["per_layer"]:
+        if cell not in m.get("workloads", [cell]):
+            continue
+        value = _module("metrics", m["name"]).read(ctx)
+        if value is not None:
+            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return out
+
+
+def _top(ns_by_name):
+    """[[name, seconds]], longest first, at most BREAKDOWN_TOP entries: past
+    that the shortest are summed under "(rest)"."""
+    pairs = sorted(ns_by_name.items(), key=lambda kv: -kv[1])
+    if len(pairs) > BREAKDOWN_TOP:
+        keep = BREAKDOWN_TOP - 1
+        pairs = pairs[:keep] + [("(rest)", sum(v for _, v in pairs[keep:]))]
+    return [[n, v * 1e-9] for n, v in pairs]
+
+
+def breakdown(reduced, by_scope):
+    """A traced line's breakdown: the device ops that took most time, the
+    longest idle gaps by host event, the device time per named scope
+    (``(none)`` for ops under none; the entries sum to the busy time), the
+    part of each scope's time that was inferred, not named by the op's own
+    metadata, and the idle time per innermost ``repro.*`` or ``bench.*``
+    host span."""
+    return {
+        "device_ops": [[n, s * 1e-9] for n, s in reduced["top_ops"]],
+        "idle_gaps": [[n, s * 1e-9] for n, s in reduced["top_gaps"]],
+        "scopes": _top(by_scope["scope_ns"]),
+        "scopes_inherited": _top(by_scope["inherited_ns"]),
+        "idle_by_span": _top(by_scope["idle_by_span"]),
+    }
+
+
+def flat_theta(theta, lead):
+    """θ values with ``lead`` leading axes as float64 (*lead axes, P):
+    each θ flattened in row-major order."""
+    theta = np.asarray(theta, np.float64)
+    return theta.reshape(*theta.shape[:lead], -1)
+
+
+def coordinates(ref, cfg, theta):
+    """The compared coordinates (..., Q) of flat θs (..., P): the
+    reference's ``coords`` where it defines one, else θ itself."""
+    coords = getattr(ref, "coords", None)
+    return theta if coords is None else coords(theta, cfg)
+
+
 def _state_to_host(state, is_flymc):
-    """θ, lp and (FlyMC) the bright rows with their cached δ, per chain."""
-    theta = np.asarray(state.sampler.theta, np.float64)
+    """θ (flat), lp and (FlyMC) the bright rows with their cached δ, per
+    chain."""
+    theta = flat_theta(state.sampler.theta, 1)
     lp = np.asarray(state.sampler.lp, np.float64)
     out = {"theta": theta, "lp": lp}
     if is_flymc:
@@ -515,12 +637,12 @@ def strata(length, k, rng):
 
 def expected_bright(ref, thetas, x, t, xi, cfg, block=1 << 16):
     """Σ_n P(z_n = 1 | θ) = Σ_n (1 - e^{-δ_n(θ)}) under the float64
-    reference, at each θ of ``thetas`` (M, D): the bright count that the
-    joint law of (θ, z) gives in expectation."""
+    reference, at each θ of ``thetas`` (M, P), flat: the bright count that
+    the joint law of (θ, z) gives in expectation."""
     from bench.reference.common import Arith
 
     f64 = Arith("f64")
-    th = np.asarray(thetas, np.float64).T  # (D, M): every θ at once
+    th = np.asarray(thetas, np.float64).T  # (P, M): every θ at once
     total = np.zeros(th.shape[1])
     for i in range(0, x.shape[0], block):
         rows = slice(i, i + block)
@@ -539,6 +661,11 @@ def compare(ref, cfg, x, t, theta_star, kept, draws, ess, is_flymc, rng,
             prec="f64", bright=None):
     """The numbers compared, and how many answers they cover.
 
+    θ is flat everywhere (module docstring): a kept state's ``theta``
+    (chains, P), ``draws`` (chains, iterations, P), ``bright``'s θ
+    (chains, S, P); ``ess`` (Q,) is over the compared coordinates
+    (:func:`coordinates`), as the reference's ``posterior`` moments are.
+
     lp_gap     spread (max - min, nats) of lp - reference lp over the kept
                states and chains: FlyMC's joint log density of (θ, z),
                regular MCMC's log posterior. A density is defined up to a
@@ -549,11 +676,12 @@ def compare(ref, cfg, x, t, theta_star, kept, draws, ess, is_flymc, rng,
                is.
     delta_gap  largest |δ - reference δ| (nats) over the bright rows of
                the kept states (FlyMC)
-    mean_z     largest |window mean - posterior mean| over θ's
+    mean_z     largest |window mean - posterior mean| over the compared
                coordinates, in combined standard errors
-    sd_gap     largest |log(window sd / posterior sd)| over θ's coordinates
+    sd_gap     largest |log(window sd / posterior sd)| over the compared
+               coordinates
     bright_gap |log((Σ bright count + 1) / (Σ expected count + 1))| over
-               ``bright`` = (θ (K, S, D), bright count (K, S)) at S window
+               ``bright`` = (θ (K, S, P), bright count (K, S)) at S window
                iterations per chain (FlyMC): the z-update's law. Under the
                joint, z given θ is Bernoulli(1 - e^{-δ_n(θ)}) per row, so a
                chain at its stationary law brightens Σ_n (1 - e^{-δ_n(θ)})
@@ -609,7 +737,8 @@ def compare(ref, cfg, x, t, theta_star, kept, draws, ess, is_flymc, rng,
         answers += counts.size
     if draws is not None:
         mean, sd, se, _ = ref.posterior(x, t, cfg, rng)
-        flat = draws.reshape(-1, draws.shape[-1])
+        got = coordinates(ref, cfg, draws)
+        flat = got.reshape(-1, got.shape[-1])
         got_mean, got_sd = flat.mean(0), flat.std(0)
         mcse = sd / np.sqrt(ess)
         with np.errstate(divide="ignore", invalid="ignore"):

@@ -11,8 +11,10 @@ trace is read by :func:`bench.trace.load`, with its conventions:
 nanoseconds on the profiler's clock, the window is the ``bench.traced``
 span, only leaf ops count.
 
-An op's scope is the innermost known scope in its instruction's op-name
-metadata, read from the HLO protos that the profile keeps of every
+An op's scope is the innermost scope in its instruction's op-name
+metadata (a path component whose name, inside any transforms, lies in one
+of the program's namespaces ``flymc.``, ``regular.`` and ``driver.``: a
+scope the program adds counts under its own name), read from the HLO protos that the profile keeps of every
 program it saw (a fusion's metadata is its root's). The compiler leaves
 some instructions without metadata (multi-output fusions, copies, sorts
 it adds). Such an instruction's scope is inferred: the commonest scope of
@@ -27,7 +29,6 @@ does not name, counts under ``(none)``.
 from __future__ import annotations
 
 import bisect
-import dataclasses
 import glob
 import os
 import re
@@ -35,30 +36,31 @@ from collections import Counter, defaultdict
 
 from bench import trace
 
-# The scopes the program puts on its ops (core/flymc.py, api/algorithm.py,
-# api/driver.py). An op counts under the innermost of these in its path.
-SCOPES = (
-    "flymc.theta", "flymc.z", "flymc.z.candidates", "flymc.z.delta",
-    "flymc.z.flips", "flymc.refresh", "regular.theta", "driver.outputs",
-    "driver.fold",
-)
+# The namespaces of the scopes the program puts on its ops
+# (core/flymc.py: flymc.theta, flymc.z, flymc.z.candidates, flymc.z.delta,
+# flymc.z.flips, flymc.refresh; api/algorithm.py: regular.theta;
+# api/driver.py: driver.outputs, driver.fold). An op counts under the
+# innermost name in one of them in its path.
+NAMESPACES = ("flymc", "regular", "driver")
 NONE = "(none)"
 SPAN_PREFIXES = ("repro.", "bench.")
 
 _WRAPPED = re.compile(r"[A-Za-z_][A-Za-z0-9_]*\((.*)\)")
+_SCOPE = re.compile(rf"(?:{'|'.join(NAMESPACES)})(?:\.[A-Za-z0-9_]+)+")
 
 
-def scope_of(path, scopes=SCOPES):
-    """The innermost scope of ``scopes`` in an op-name path, or NONE.
+def scope_of(path):
+    """The innermost scope in an op-name path, or NONE.
 
-    A path component may be wrapped by transforms ("vmap(flymc.theta)",
-    "transpose(jvp(flymc.theta))"); the name inside counts.
+    A scope is a path component named ``<namespace>.<name>[.<name>...]``
+    (NAMESPACES). It may be wrapped by transforms ("vmap(flymc.theta)",
+    "transpose(jvp(flymc.theta.grad))"); the name inside counts.
     """
     found = NONE
     for part in (path or "").split("/"):
         while (m := _WRAPPED.fullmatch(part)) is not None:
             part = m.group(1)
-        if part in scopes:
+        if _SCOPE.fullmatch(part):
             found = part
     return found
 
@@ -106,7 +108,7 @@ def _newest(trace_dir):
     return max(paths, key=os.path.getmtime)
 
 
-def load(trace_dir, scopes=SCOPES):
+def load(trace_dir):
     """({plane: [(name, start, end, (scope, inherited))]}, host spans,
     programs without an HLO proto) for each TPU plane of the newest
     ``.xplane.pb`` under trace_dir: the ops and spans of
@@ -115,7 +117,7 @@ def load(trace_dir, scopes=SCOPES):
     programs that ran but whose ops the profile cannot place."""
     devices, spans = trace.load(trace_dir)
     with open(_newest(trace_dir), "rb") as f:
-        programs = {name: instruction_scopes(proto, scopes)
+        programs = {name: instruction_scopes(proto)
                     for name, proto in hlo_protos(f.read()).items()}
     out, no_proto = {}, set()
     for plane, (ops, modules) in devices.items():
@@ -137,7 +139,7 @@ def op_scope(name, start, modules, programs):
     return (program or {}).get(trace.short_name(name), (NONE, False))
 
 
-def reduce_trace(trace_dir, scopes=SCOPES):
+def reduce_trace(trace_dir):
     """The traced window of a recorded trace, by scope and by span.
 
     Returns, averaged over the TPU planes as :func:`bench.trace.reduce_trace`
@@ -147,7 +149,7 @@ def reduce_trace(trace_dir, scopes=SCOPES):
     metadata}, ``idle_by_span`` {span: idle ns} and ``no_proto``, the
     programs whose ops count under NONE for want of an HLO proto.
     """
-    devices, spans, no_proto = load(trace_dir, scopes)
+    devices, spans, no_proto = load(trace_dir)
     if not devices:
         raise ValueError("the trace holds no TPU plane")
     window = trace.window_of(spans)
@@ -258,11 +260,11 @@ def hlo_protos(xspace):
     return {}
 
 
-def instruction_scopes(hlo_proto, scopes=SCOPES):
+def instruction_scopes(hlo_proto):
     """{instruction name: (scope, inherited)} of one program's serialized
     HloProto.
 
-    An instruction with op-name metadata takes its innermost known scope
+    An instruction with op-name metadata takes its innermost scope
     (NONE where it names none), and so does a fusion without any whose
     fused root has some: ``inherited`` False. Any other instruction's
     scope is inferred, ``inherited`` True: the commonest scope of the
@@ -311,7 +313,7 @@ def instruction_scopes(hlo_proto, scopes=SCOPES):
 
     def known(rec):
         """The scope the instruction's own metadata names, or None."""
-        scope = scope_of(rec["op_name"], scopes)
+        scope = scope_of(rec["op_name"])
         return None if scope == NONE else scope
 
     up_memo, down_memo = {}, {}
@@ -342,11 +344,11 @@ def instruction_scopes(hlo_proto, scopes=SCOPES):
 
     def scope(iid, rec):
         if rec["op_name"]:
-            return scope_of(rec["op_name"], scopes), False
+            return scope_of(rec["op_name"]), False
         if rec["opcode"] == "fusion" and rec["called"]:
             root = instr.get(roots.get(rec["called"][0]))
             if root is not None and root["op_name"]:
-                return scope_of(root["op_name"], scopes), False
+                return scope_of(root["op_name"]), False
         inferred = up(iid) or down(iid)
         return (inferred, True) if inferred else (NONE, False)
 
@@ -355,45 +357,21 @@ def instruction_scopes(hlo_proto, scopes=SCOPES):
 
 # ---- the per-layer numbers these give --------------------------------------
 
-HOST_PARTS = ("dispatch_s", "regrow_s", "fold_s", "hook_s")
+
+def under(scope, root):
+    """Whether ``scope`` is ``root`` or a scope named under it
+    ("flymc.z.flips" is under "flymc.z"; "flymc.zeta" is not)."""
+    return scope == root or scope.startswith(root + ".")
 
 
-def phase_us(scopes_ns, chain_iters, match):
-    """Device µs per chain-iteration in the scopes that ``match`` accepts,
-    or None where none of them ran."""
-    ns = [v for k, v in scopes_ns.items() if match(k)]
-    if not ns or chain_iters <= 0:
+def phase_us(ctx, roots):
+    """Device µs per traced chain-iteration in the scopes ``roots`` and
+    those under them (:func:`under`), from a metric reader's ``ctx``; None
+    where the run holds no scopes or none of these ran."""
+    if ctx.scopes is None or ctx.traced["chain_iters"] <= 0:
         return None
-    return sum(ns) * 1e-3 / chain_iters
-
-
-def step_metrics(scopes_ns, chain_iters):
-    """step.theta_us, step.z_us and step.flips_us, where they read
-    something: device µs per traced chain-iteration in the θ-phase
-    (FlyMC's or regular MCMC's), the whole z-phase and its partition
-    swaps."""
-    out = {
-        "step.theta_us": phase_us(scopes_ns, chain_iters,
-                                  lambda k: k in ("flymc.theta",
-                                                  "regular.theta")),
-        "step.z_us": phase_us(scopes_ns, chain_iters,
-                              lambda k: k.startswith("flymc.z")),
-        "step.flips_us": phase_us(scopes_ns, chain_iters,
-                                  lambda k: k == "flymc.z.flips"),
-    }
-    return {k: v for k, v in out.items() if v is not None}
-
-
-def counter_delta(first, last):
-    """Field-by-field difference of two DriverCounters snapshots."""
-    a, b = dataclasses.asdict(first), dataclasses.asdict(last)
-    return {k: b[k] - a[k] for k in a}
-
-
-def host_share(delta, seconds):
-    """% of the window's ``seconds`` the driver's host work took (dispatch,
-    regrow, fold and the on_chunk hook; the overflow wait is the
-    device's), both without the profiler's start and stop
-    (``delta["profiler_s"]``, where given)."""
-    p = delta.get("profiler_s", 0.0)
-    return 100.0 * (sum(delta[k] for k in HOST_PARTS) - p) / (seconds - p)
+    ns = [v for k, v in ctx.scopes["scope_ns"].items()
+          if any(under(k, r) for r in roots)]
+    if not ns:
+        return None
+    return sum(ns) * 1e-3 / ctx.traced["chain_iters"]

@@ -58,6 +58,24 @@ def test_result_keys_and_checks_last(tiny_logistic):
     json.dumps(res)
 
 
+def test_window_counts_the_drivers_host_work(tiny_logistic):
+    """The window's counters on a run with the profiler off, and the host
+    share a traced run would read from them."""
+    from types import SimpleNamespace
+
+    from bench import harness
+
+    res = tiny_logistic
+    driver = res["window"]["driver"]
+    assert driver["chunks"] >= 1 and driver["reruns"] == 0
+    assert driver["profiler_s"] == 0.0
+    assert 0 < driver["longest_boundary_s"] <= res["window"]["seconds"]
+    share = harness._module("metrics", "driver.host_share").read(
+        SimpleNamespace(window=res["window"]))
+    assert 0 < share < 100
+    assert "breakdown" not in res
+
+
 @pytest.fixture(scope="module")
 def tiny_logistic():
     from bench import harness

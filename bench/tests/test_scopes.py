@@ -1,12 +1,33 @@
 """Device time by scope and idle time by span, on traces written by hand."""
 
+import re
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
-from bench import phases, scopes, trace
+from bench import harness, scopes, trace
 
 W = "jit(chunk)/while/body/closed_call/"
+# The scopes the program put on its ops when a closed list of them was
+# what bench/scopes.py recognised.
+OLD_SCOPES = (
+    "flymc.theta", "flymc.z", "flymc.z.candidates", "flymc.z.delta",
+    "flymc.z.flips", "flymc.refresh", "regular.theta", "driver.outputs",
+    "driver.fold",
+)
+
+
+def old_scope_of(path):
+    """The innermost scope of OLD_SCOPES in an op-name path, or NONE."""
+    found = scopes.NONE
+    for part in (path or "").split("/"):
+        while (m := re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*\((.*)\)",
+                                 part)) is not None:
+            part = m.group(1)
+        if part in OLD_SCOPES:
+            found = part
+    return found
 
 
 def test_scope_of_takes_the_innermost_known_scope():
@@ -18,16 +39,22 @@ def test_scope_of_takes_the_innermost_known_scope():
         "flymc.theta")
     assert scopes.scope_of("jit(fold)/driver.fold/while/body/dus") == (
         "driver.fold")
-    # names that only contain a scope's name are not the scope
-    assert scopes.scope_of(W + "vmap(flymc.zz)/add") == scopes.NONE
-    assert scopes.scope_of(W + "vmap()/vmap(jit(_threefry_split))") == (
-        scopes.NONE)
-    assert scopes.scope_of(None) == scopes.NONE
+    # a scope the program adds counts under its own name, through any
+    # transforms
+    assert scopes.scope_of(
+        W + "vmap(flymc.theta)/transpose(jvp(flymc.theta.grad))/dot") == (
+        "flymc.theta.grad")
+    assert scopes.scope_of(W + "vmap(flymc.zz)/add") == "flymc.zz"
+    # names outside the namespaces, or only a namespace, are no scope
+    for path in (W + "vmap(flymc)/add", W + "flymcz.a/add",
+                 W + "vmap()/vmap(jit(_threefry_split))", "jit(f)/repro.x",
+                 None):
+        assert scopes.scope_of(path) == scopes.NONE, path
 
 
-def _ops():
+def _ops(scope_of=scopes.scope_of):
     """Ops with their scopes, as :func:`scopes.load` gives them."""
-    return [(name, s, e, scopes.scope_of(path)) for name, s, e, path in [
+    return [(name, s, e, scope_of(path)) for name, s, e, path in [
         # a loop op containing the others is not a leaf
         ("%while.1", 0, 100, "jit(chunk)/while"),
         # starts before the window
@@ -41,6 +68,14 @@ def _ops():
         ("%dynamic-update-slice.5", 85, 90, "jit(chunk)/while/body/dus"),
         ("%fusion.6", 95, 105, W + "vmap(flymc.theta)/dot"),
     ]]
+
+
+def test_the_namespace_rule_reads_the_old_scopes_as_the_closed_list_did():
+    assert scopes.scope_ns(_ops(), (10, 110)) == scopes.scope_ns(
+        _ops(old_scope_of), (10, 110))
+    paths = [path for *_, path in _ops(lambda p: p)]
+    assert [scopes.scope_of(p) for p in paths] == [
+        old_scope_of(p) for p in paths]
 
 
 def test_scope_ns_splits_the_busy_time_once():
@@ -88,21 +123,54 @@ def test_the_kernels_keep_their_times_under_the_scoped_names():
     assert a["busy_ns"] == b["busy_ns"]
 
 
-def test_step_metrics_and_host_share():
+def _ctx(scope_ns=None, chain_iters=1000, driver=None, seconds=25.0):
+    return SimpleNamespace(
+        scopes=None if scope_ns is None else {"scope_ns": scope_ns,
+                                              "inherited_ns": {}},
+        traced={"chain_iters": chain_iters},
+        window={"seconds": seconds, "driver": driver})
+
+
+def _read(name, ctx):
+    return harness._module("metrics", name).read(ctx)
+
+
+STEP_METRICS = ("step.theta_us", "step.z_us", "step.flips_us")
+
+
+def test_step_readers_split_the_step_by_scope():
     ns = {"flymc.theta": 4e6, "flymc.z": 1e6, "flymc.z.candidates": 3e6,
           "flymc.z.flips": 2e6, "driver.outputs": 5e5, scopes.NONE: 1e5}
-    got = scopes.step_metrics(ns, 1000)
+    got = {m: _read(m, _ctx(ns)) for m in STEP_METRICS}
     assert got == pytest.approx({"step.theta_us": 4.0, "step.z_us": 6.0,
                                  "step.flips_us": 2.0})
-    assert scopes.step_metrics({"regular.theta": 7e6}, 1000) == {
-        "step.theta_us": pytest.approx(7.0)}
-    assert scopes.step_metrics(ns, 0) == {}
+    assert _read("step.theta_us", _ctx({"regular.theta": 7e6})) == (
+        pytest.approx(7.0))
+    # a scope the program adds under a phase counts in it
+    ns = {"flymc.theta": 4e6, "flymc.theta.grad": 1e6, "flymc.zeta": 9e6}
+    assert _read("step.theta_us", _ctx(ns)) == pytest.approx(5.0)
+    assert _read("step.z_us", _ctx(ns)) is None
+
+
+@pytest.mark.parametrize("name", STEP_METRICS)
+def test_step_readers_read_nothing_without_scopes(name):
+    assert _read(name, _ctx()) is None
+    assert _read(name, _ctx({scopes.NONE: 1e6})) is None
+    assert _read(name, _ctx({"flymc.theta": 1e6, "flymc.z.flips": 1e6,
+                             "regular.theta": 1e6}, chain_iters=0)) is None
+
+
+def test_host_share_reader():
     delta = {"dispatch_s": 0.1, "wait_s": 20.0, "regrow_s": 0.0,
-             "fold_s": 0.2, "hook_s": 0.2}
-    assert scopes.host_share(delta, 25.0) == pytest.approx(2.0)
+             "fold_s": 0.2, "hook_s": 0.2, "profiler_s": 0.0}
+    assert _read("driver.host_share", _ctx(driver=delta)) == (
+        pytest.approx(2.0))
     # the profiler's start and stop, inside the hook, leave both sides
     delta = dict(delta, hook_s=5.2, profiler_s=5.0)
-    assert scopes.host_share(delta, 30.0) == pytest.approx(2.0)
+    assert _read("driver.host_share", _ctx(driver=delta, seconds=30.0)) == (
+        pytest.approx(2.0))
+    # a program without counters gives nothing to read
+    assert _read("driver.host_share", _ctx()) is None
 
 
 class Event:
@@ -110,22 +178,35 @@ class Event:
         self.driver, self.committed, self.state = driver, committed, None
 
 
-def test_boundaries_give_the_window_and_its_longest_stretch(monkeypatch):
+class Clock:
+    def __init__(self, *times):
+        self.times = iter(times)
+
+    def __call__(self):
+        return next(self.times)
+
+
+def _window(seconds, trace_dir=None, trace_seconds=None):
+    return harness.Window(seconds, trace_seconds or seconds, trace_dir, 0,
+                          np.random.default_rng(0),
+                          SimpleNamespace(active=False))
+
+
+def test_window_keeps_the_counters_and_its_longest_stretch(monkeypatch):
     from repro.api.driver import DriverCounters
 
-    clock = iter([0.0, 1.0, 3.5, 4.0, 9.0])
-    monkeypatch.setattr(phases.time, "perf_counter", lambda: next(clock))
+    monkeypatch.setattr(harness.time, "perf_counter",
+                        Clock(0.0, 1.0, 3.5, 4.0))
     snaps = [DriverCounters(chunks=1, wait_s=0.5, hook_s=0.1),
              DriverCounters(chunks=2, wait_s=1.4, hook_s=0.2),
              DriverCounters(chunks=3, reruns=1, rerun_iters=40, wait_s=2.5,
                             dispatch_s=0.3, regrow_s=0.6, hook_s=0.3),
              DriverCounters(chunks=4, reruns=1, rerun_iters=40, wait_s=3.0,
                             dispatch_s=0.3, regrow_s=0.6, hook_s=0.4)]
-
-    b = phases.Boundaries(lambda e: False)
-    for s in snaps:
-        assert b(Event(s)) is False
-    got = b.window()
+    win = _window(4.0)
+    assert [win(Event(s, i)) for i, s in enumerate(snaps)] == [
+        False, False, False, True]
+    got = win.driver()
     assert got["chunks"] == 3 and got["reruns"] == 1
     assert got["rerun_iters"] == 40
     assert got["wait_s"] == pytest.approx(2.5)
@@ -134,45 +215,36 @@ def test_boundaries_give_the_window_and_its_longest_stretch(monkeypatch):
     assert got["longest_wait_s"] == pytest.approx(1.1)
     assert got["longest_host_s"] == pytest.approx(0.3 + 0.6 + 0.1)
     # a program without counters gives nothing to read
-    empty = phases.Boundaries(lambda e: True)
-    assert empty(object()) is True and empty.window() is None
+    monkeypatch.setattr(harness.time, "perf_counter", Clock(0.0, 9.0))
+    empty = _window(4.0)
+    bare = SimpleNamespace(state=None, committed=0)
+    assert empty(bare) is False and empty(bare) is True
+    assert empty.driver() is None
 
 
-def test_boundaries_time_the_hooks_that_start_or_stop_the_profiler(
-        monkeypatch):
+def test_window_times_the_profilers_start_and_stop(monkeypatch):
     from repro.api.driver import DriverCounters
 
-    class Hook:
-        """Starts "the profiler" at the first boundary and stops it at the
-        third, as the harness's window does."""
+    clock = Clock(0.0, 0.0, 0.5,  # boundary 1, the start around it
+                  1.0, 2.0,  # boundaries 2 and 3
+                  4.0, 4.0, 6.0,  # boundary 4, the stop around it
+                  7.0)  # boundary 5 ends the window
+    monkeypatch.setattr(harness.time, "perf_counter", clock)
 
-        tracing = None
+    def start(self, committed):
+        self.tracing, self.trace_t0 = object(), 0.5
 
-        def __call__(self, event):
-            if event.committed == 1:
-                self.tracing = object()
-            elif event.committed == 3:
-                self.tracing = None
-            return False
+    def stop(self, committed):
+        self.tracing = None
 
-    # arrival, then the end of each toggling hook
-    clock = iter([0.0, 0.5, 1.0, 2.0, 4.0, 5.0, 6.0])
-    monkeypatch.setattr(phases.time, "perf_counter", lambda: next(clock))
-    b = phases.Boundaries(Hook())
+    monkeypatch.setattr(harness.Window, "_start_trace", start)
+    monkeypatch.setattr(harness.Window, "_stop_trace", stop)
+    win = _window(7.0, trace_dir="unused", trace_seconds=3.0)
     for i in range(1, 6):
-        b(Event(DriverCounters(chunks=i), committed=i))
-    got = b.window()
+        win(Event(DriverCounters(chunks=i), committed=i))
+    got = win.driver()
     assert got["profiler_s"] == pytest.approx(0.5 + 2.0)
     assert got["chunks"] == 4
-
-
-def test_instrumented_refuses_a_harness_that_skips_its_wrappers():
-    with pytest.raises(RuntimeError, match="boundaries"):
-        with phases.instrumented(False):
-            pass
-    with pytest.raises(RuntimeError, match="scopes"):
-        with phases.instrumented(True):
-            pass
 
 
 # ---- scopes from the HLO protos a profile holds -----------------------------
@@ -356,60 +428,53 @@ def test_reduce_trace_of_a_recorded_file(tmp_path):
     assert first["window_ns"] == 100
 
 
-def test_phases_run_reads_a_traced_run_end_to_end(tmp_path, monkeypatch):
-    """phases.run around a stand-in for the harness that reaches both of
-    its wrappers, as bench/harness.py does: the window's hook through
-    api.sample, and the trace reduction on a recorded file."""
-    import numpy as np
-
-    from bench import harness
-    from repro import api
-    from repro.api.driver import DriverCounters
-
+def test_a_traced_line_carries_the_phase_split(tmp_path):
+    """A recorded trace through the harness's own reduction, readers and
+    breakdown: the step's phases and the driver's host share on the line,
+    the scopes summing to the busy time."""
     out = tmp_path / "plugins" / "profile" / "run"
     out.mkdir(parents=True)
     (out / "host.xplane.pb").write_bytes(_xspace(
         [("fusion.2", 10, 50), ("fusion.1", 50, 90)],
-        [("jit_chunk(3)", 0, 100)], [("bench.traced", 10, 110)],
+        [("jit_chunk(3)", 0, 100)],
+        [("bench.traced", 10, 110), ("repro.sample.wait", 95, 105)],
         {"jit_chunk(3)": _hlo_proto()}))
-
-    def sample(alg, key, n, *, on_chunk=None, **kw):
-        for i in range(1, 4):
-            if on_chunk(Event(DriverCounters(chunks=i, hook_s=0.01 * i),
-                              committed=i)):
-                break
-
-    def fake_run(cell, seed, seconds, trace=False, **kw):
-        win = harness.Window(0.0, 0.0, None, 0, np.random.default_rng(0),
-                             SimpleNamespace(active=False))
-        api.sample(None, None, 0, on_chunk=win)
-        from bench import trace as trace_lib
-
-        _, red = trace_lib.reduce_trace(str(tmp_path))
-        return {"correct": True, "metrics": {},
-                "device": {"window_s": red["window_ns"] * 1e-9},
-                "breakdown": {},
-                "window": {"seconds": 2.0, "traced_chain_iters": 10},
-                "checks": {}}
-
-    monkeypatch.setattr(api, "sample", sample)
-    monkeypatch.setattr(harness, "run", fake_run)
-    res = phases.run("logistic-rwmh.map.c4", 1, 2.0, trace=True)
-    assert list(res)[-1] == "checks"
-    assert res["breakdown"]["scopes"] == pytest.approx(
-        {"flymc.theta": 40e-9, "flymc.z.flips": 40e-9})
-    assert res["breakdown"]["scopes_inherited"] == pytest.approx(
-        {"flymc.z.flips": 40e-9})
-    assert res["breakdown"]["idle_by_span"] == pytest.approx(
-        {"bench.traced": 20e-9})
-    assert res["device"]["own_none_share"] == pytest.approx(50.0)
-    assert res["breakdown"]["programs_without_hlo"] == []
-    m = res["metrics"]
-    assert m["step.theta_us"]["value"] == pytest.approx(40e-3 / 10)
+    reduced, by_scope = harness.reduce_traced(str(tmp_path))
+    cell = "logistic-rwmh.map.c4"
+    _, cfg, traffic, _ = harness.find_cell(cell)
+    delta = {"dispatch_s": 0.01, "wait_s": 1.9, "regrow_s": 0.0,
+             "fold_s": 0.0, "hook_s": 0.01, "profiler_s": 0.0}
+    ctx = SimpleNamespace(
+        cfg=cfg, traffic=traffic, flymc=True, trace=reduced,
+        scopes=by_scope, traced={"chain_iters": 10, "queries": 1000},
+        window={"chain_iters": 100, "seconds": 2.0, "min_ess": 5.0,
+                "queries_per_iter": 10.0, "driver": delta},
+        peaks=harness.load_json(harness.BENCH / "peaks.json")[
+            "TPU v5 lite"])
+    m = harness.per_layer(harness.benchmark_spec(), cell, ctx)
+    assert m["step.theta_us"] == {"value": pytest.approx(40e-3 / 10),
+                                  "unit": "us/iter"}
+    assert m["step.z_us"]["value"] == pytest.approx(40e-3 / 10)
     assert m["step.flips_us"]["value"] == pytest.approx(40e-3 / 10)
-    # the window (0 s) ends at the second boundary: one hook in it
-    assert res["window"]["driver"]["chunks"] == 1
-    assert m["driver.host_share"]["value"] == pytest.approx(100 * 0.01 / 2)
+    assert m["driver.host_share"] == {"value": pytest.approx(1.0),
+                                      "unit": "%"}
+    b = harness.breakdown(reduced, by_scope)
+    assert b["scopes"] == [["flymc.theta", pytest.approx(40e-9)],
+                           ["flymc.z.flips", pytest.approx(40e-9)]]
+    assert sum(v for _, v in b["scopes"]) == pytest.approx(
+        reduced["busy_ns"] * 1e-9)
+    assert b["scopes_inherited"] == [["flymc.z.flips", pytest.approx(40e-9)]]
+    # the one gap, [90, 110], by the span over its middle
+    assert b["idle_by_span"] == [["repro.sample.wait", pytest.approx(20e-9)]]
+
+
+def test_breakdown_lists_keep_ten_entries_and_the_whole_sum():
+    ns = {f"flymc.s{i}": float(i) for i in range(1, 13)}
+    got = harness._top(ns)
+    assert len(got) == harness.BREAKDOWN_TOP
+    assert got[0] == ["flymc.s12", pytest.approx(12e-9)]
+    assert got[-1] == ["(rest)", pytest.approx((1 + 2 + 3) * 1e-9)]
+    assert sum(v for _, v in got) == pytest.approx(78e-9)
 
 
 def test_scopes_from_a_recorded_cpu_profile(tmp_path):
@@ -443,19 +508,3 @@ def test_scopes_from_a_recorded_cpu_profile(tmp_path):
     assert own and {k: got[k] for k in own} == {
         k: (scopes.scope_of(v), False) for k, v in own.items()}
     assert {"flymc.theta", "flymc.z.flips"} <= {s for s, _ in got.values()}
-
-
-def test_phases_run_counts_an_untraced_run_of_a_shrunk_cell():
-    """The real harness on the CPU: window.driver and driver.host_share
-    on a run with the profiler off."""
-    from bench.tests.test_run import TINY, TINY_TRAFFIC
-
-    res = phases.run("logistic-rwmh.map.c4", 5, 2.0, cfg_over=TINY,
-                     traffic_over=TINY_TRAFFIC)
-    driver = res["window"]["driver"]
-    assert driver["chunks"] >= 1 and driver["reruns"] == 0
-    assert driver["profiler_s"] == 0.0
-    assert 0 < driver["longest_boundary_s"] <= res["window"]["seconds"]
-    assert 0 < res["metrics"]["driver.host_share"]["value"] < 100
-    assert "scopes" not in res.get("breakdown", {})
-    assert list(res)[-1] == "checks"
