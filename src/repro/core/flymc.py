@@ -119,6 +119,7 @@ def make_joint_logpost(
     stats: CollapsedStats,
     bright_idx: jax.Array,
     bright_mask: jax.Array,
+    grad_scope: str | None = None,
 ) -> samplers.LogDensityFn:
     """f(θ) -> (joint log posterior, δ on the bright buffer).
 
@@ -138,7 +139,8 @@ def make_joint_logpost(
     kernel (gather + δ + masked log L̃ reduction in one pass, gradient via
     its custom VJP) for bounds exposing the
     :class:`~repro.core.bounds.FusedBound` hook — with interpret-mode
-    fallback off-TPU so both paths run everywhere.
+    fallback off-TPU so both paths run everywhere. ``grad_scope`` names
+    that VJP's backward ops (see ``bright_glm``).
     """
 
     if spec.backend == "pallas":
@@ -161,7 +163,8 @@ def make_joint_logpost(
 
             delta, s = bright_glm(
                 _gather_layout_of(data), data.t, data.xi, bright_idx,
-                n_bright, theta, family=fam, **kernel_kwargs,
+                n_bright, theta, family=fam, grad_scope=grad_scope,
+                **kernel_kwargs,
             )
             for ax in spec.axis_names:
                 s = jax.lax.psum(s, ax)
@@ -203,7 +206,8 @@ def _refresh_sampler(
     idx, mask = brightness.bright_buffer(bright, spec.capacity)
     delta = jnp.take(delta_full, idx)
     if spec.needs_grad():
-        f = make_joint_logpost(spec, data, stats, idx, mask)
+        f = make_joint_logpost(spec, data, stats, idx, mask,
+                               grad_scope="flymc.refresh.grad")
         (lp, aux), grad = jax.value_and_grad(f, has_aux=True)(theta)
         return samplers.SamplerState(theta, lp, grad, aux), bright.num
     # lp from cached δ — zero new likelihood queries.
@@ -446,7 +450,9 @@ def flymc_step(
     ``flymc.theta`` (bright buffer, θ-kernel, δ-cache scatter), ``flymc.z``
     (the z-update; the fused engine adds ``flymc.z.candidates``,
     ``flymc.z.delta`` and ``flymc.z.flips`` inside it) and
-    ``flymc.refresh`` (sampler refresh and step-size adaptation).
+    ``flymc.refresh`` (sampler refresh and step-size adaptation). A
+    gradient θ-kernel on the pallas backend runs the fused likelihood's
+    backward under ``flymc.theta.grad`` and ``flymc.refresh.grad``.
 
     Distributed (spec.axis_names non-empty, inside shard_map): the θ-kernel
     runs replicated with identical keys on every shard (identical proposals
@@ -461,7 +467,8 @@ def flymc_step(
     # ---- θ | z -------------------------------------------------------------
     with jax.named_scope("flymc.theta"):
         idx, mask = brightness.bright_buffer(state.bright, spec.capacity)
-        f = make_joint_logpost(spec, data, stats, idx, mask)
+        f = make_joint_logpost(spec, data, stats, idx, mask,
+                               grad_scope="flymc.theta.grad")
         kernel = samplers.bind(spec.kernel, f, spec.kernel_kwargs)
         new_sampler, info = kernel(
             key_theta, state.sampler, jnp.exp(state.log_step)

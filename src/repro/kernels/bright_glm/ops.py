@@ -32,6 +32,7 @@ This is the ``backend="pallas"`` entry point used by
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from functools import lru_cache, partial
 
 import jax
@@ -81,7 +82,7 @@ def _pallas_dispatch(family, nu, sigma, n_classes, block_rows, interpret):
 
 
 def _forward(cfg, x_rows, t, xi, idx, n_bright, theta):
-    family, nu, sigma, block_rows, interpret = cfg
+    family, nu, sigma, block_rows, interpret, _ = cfg
     n, _, dp = x_rows.shape
     d = theta.shape[-1]
     c = idx.shape[0]
@@ -143,18 +144,20 @@ def _vjp_fwd(cfg, x_rows, t, xi, idx, n_bright, theta):
 
 def _vjp_bwd(cfg, res, cts):
     x_rows, t, xi, idx, n_bright, theta = res
-    if jnp.issubdtype(t.dtype, jnp.integer):  # softmax class ids
-        fn = lambda x_, xi_, th: _ref_outputs(cfg, x_, t, xi_, idx, n_bright,
-                                              th)
-        _, vjp = jax.vjp(fn, x_rows, xi, theta)
-        dx, dxi, dth = vjp(cts)
-        dt = None
-    else:
-        fn = lambda x_, t_, xi_, th: _ref_outputs(
-            cfg, x_, t_, xi_, idx, n_bright, th
-        )
-        _, vjp = jax.vjp(fn, x_rows, t, xi, theta)
-        dx, dt, dxi, dth = vjp(cts)
+    grad_scope = cfg[5]
+    with jax.named_scope(grad_scope) if grad_scope else nullcontext():
+        if jnp.issubdtype(t.dtype, jnp.integer):  # softmax class ids
+            fn = lambda x_, xi_, th: _ref_outputs(cfg, x_, t, xi_, idx,
+                                                  n_bright, th)
+            _, vjp = jax.vjp(fn, x_rows, xi, theta)
+            dx, dxi, dth = vjp(cts)
+            dt = None
+        else:
+            fn = lambda x_, t_, xi_, th: _ref_outputs(
+                cfg, x_, t_, xi_, idx, n_bright, th
+            )
+            _, vjp = jax.vjp(fn, x_rows, t, xi, theta)
+            dx, dt, dxi, dth = vjp(cts)
     return dx, dt, dxi, None, None, dth
 
 
@@ -173,6 +176,7 @@ def bright_glm(
     sigma: float = 1.0,
     block_rows: int = 128,
     interpret: bool | None = None,
+    grad_scope: str | None = None,
 ):
     """Fused bright-point evaluation. Returns (delta (C,), total scalar).
 
@@ -181,8 +185,11 @@ def bright_glm(
     (``bounds.with_gather_layout``), never per call. Forward and backward
     read only it.
 
-    Differentiable (custom VJP); ``interpret=None`` auto-selects interpret
-    mode off-TPU so the same call sites run everywhere. Under ``jax.vmap``
+    Differentiable (custom VJP); ``grad_scope`` names the backward's ops
+    (a ``jax.named_scope``; the forward's are never named by it), so a
+    device trace tells the gradient from the forward. ``interpret=None``
+    auto-selects interpret mode off-TPU so the same call sites run
+    everywhere. Under ``jax.vmap``
     over the chain axis the pallas dispatch batches into a single
     chain-grid megakernel (see :mod:`repro.kernels.common`).
     """
@@ -199,5 +206,6 @@ def bright_glm(
         )
     if interpret is None:
         interpret = common.default_interpret()
-    cfg = (family, float(nu), float(sigma), int(block_rows), bool(interpret))
+    cfg = (family, float(nu), float(sigma), int(block_rows), bool(interpret),
+           grad_scope)
     return _bright_glm_vjp(cfg, x_rows, t, xi, idx, n_bright, theta)

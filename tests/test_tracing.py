@@ -3,9 +3,12 @@ and fold programs, host spans in a profile of ``api.sample``, and the
 driver's counters (``ChunkEvent.driver``, ``Trace.driver``)."""
 
 import glob
+import importlib
 import os
 import re
+import sys
 from collections import Counter
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -13,12 +16,14 @@ import pytest
 
 from repro import api
 from repro.api import driver
-from repro.data import logistic_data
+from repro.data import logistic_data, softmax_data
 from repro.models.bayes_glm import GLMModel
 
 jax.config.update("jax_platform_name", "cpu")
 
 N, D = 400, 4
+K = 3  # classes of the softmax model
+ROOT = Path(__file__).resolve().parents[1]
 _WRAPPED = re.compile(r"[A-Za-z_][A-Za-z0-9_]*\((.*)\)")
 FLYMC_SCOPES = ("flymc.theta", "flymc.z", "flymc.z.candidates",
                 "flymc.z.delta", "flymc.z.flips", "flymc.refresh",
@@ -43,9 +48,9 @@ def _scopes(op_name):
     return out
 
 
-def _chunk_op_names(alg, chains):
+def _chunk_op_names(alg, chains, theta_shape=(D,)):
     keys = jax.random.split(jax.random.key(0), chains)
-    pos = jnp.zeros((chains, D))
+    pos = jnp.zeros((chains, *theta_shape))
     state = jax.vmap(alg.init_data, in_axes=(0, 0, None, None))(
         keys, pos, alg.data, alg.stats)
     if chains == 1:
@@ -92,6 +97,59 @@ def test_jnp_engine_and_regular_chunk_programs_carry_their_scopes(model):
     parts = {p for n in _chunk_op_names(reg, 2) for p in _scopes(n)}
     assert {"regular.theta", "driver.outputs"} <= parts
     assert not any(p.startswith("flymc.") for p in parts)
+
+
+GRAD_SCOPES = {"flymc.theta.grad": "flymc.theta",
+               "flymc.refresh.grad": "flymc.refresh"}
+
+
+def _scope_of():
+    """``bench.scopes.scope_of``: how the benchmark names an op's scope."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    return importlib.import_module("bench.scopes").scope_of
+
+
+@pytest.fixture(scope="module")
+def softmax_model():
+    data = softmax_data(jax.random.key(1), n=N, d=D, k=K, sharpness=1.0)
+    return GLMModel.softmax(data, n_classes=K, prior_scale=1.0)
+
+
+@pytest.mark.parametrize("chains", [1, 4])
+def test_gradient_backward_carries_the_grad_scopes(softmax_model, chains):
+    """MALA through the fused kernel: the custom VJP's backward is named
+    by the phase that differentiates it, and no forward op is."""
+    scope_of = _scope_of()
+    alg = api.firefly(softmax_model, kernel="mala", capacity=64,
+                      cand_capacity=64, q_db=0.05, step_size=0.01,
+                      backend="pallas", z_backend="fused")
+    names = _chunk_op_names(alg, chains, (K, D))
+    graded = [n for n in names if scope_of(n) in GRAD_SCOPES]
+    assert {scope_of(n) for n in graded} == set(GRAD_SCOPES)
+    for n in graded:
+        # A backward op, under the phase that asked for the gradient.
+        assert "transpose(" in n, n
+        assert GRAD_SCOPES[scope_of(n)] in _scopes(n), n
+    # The backward's work, the rows' gather and their logits, is named.
+    kinds = {n.rsplit("/", 1)[-1] for n in graded}
+    assert {"gather", "dot_general"} <= kinds, kinds
+    # No forward op carries a grad scope.
+    forward = [n for n in names if "transpose(" not in n]
+    assert forward
+    assert not {p for n in forward for p in _scopes(n)} & set(GRAD_SCOPES)
+
+
+def test_no_grad_scope_without_a_gradient(softmax_model):
+    scope_of = _scope_of()
+    alg = api.firefly(softmax_model, kernel="rwmh", capacity=64,
+                      cand_capacity=64, q_db=0.05, step_size=0.01,
+                      backend="pallas", z_backend="fused")
+    names = _chunk_op_names(alg, 4, (K, D))
+    assert "flymc.theta" in {scope_of(n) for n in names}
+    parts = {p for n in names for p in _scopes(n)}
+    assert not parts & set(GRAD_SCOPES)
+    assert not any(scope_of(n) in GRAD_SCOPES for n in names)
 
 
 def test_fold_program_carries_its_scope(model):
