@@ -13,14 +13,21 @@ and for host-side use. On TPU the per-round update is batched, two ways:
     compaction. O(N) memory-bound sweep; the ``z_backend="jnp"`` engine's
     path (and the one-time init path).
   * :func:`apply_flips` — the swap updates *vectorized over a round's
-    flips*: O(changed) masked scatters with fixed (capacity-shaped)
-    intermediates, no length-N cumsum. The ``z_backend="fused"`` engine's
-    path, which keeps per-step non-likelihood work proportional to the
-    touched subset (Angelino et al.'s streaming prescription).
+    flips*, no length-N cumsum: fixed-shape vector work over the bright
+    capacity C and the candidate buffer, and index traffic into the
+    N-sized ``arr``/``tab`` bounded by a static ``pair_capacity`` (C on
+    the ``z_backend="fused"`` engine's path). On a TPU every scatter or
+    gather index entry costs about one memory tile, live or dropped, so
+    the update costs O(``pair_capacity``) tiles however few data flip.
+    It is exact while the round's brightened count fits
+    ``pair_capacity``, which holds whenever the new bright count fits C;
+    past that the result is unspecified, and the engine's step flags the
+    overflow that makes the driver discard and re-run it.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -94,23 +101,63 @@ def batch_update(state: BrightState, z_new: jax.Array) -> BrightState:
     return from_z(z_new)
 
 
+def _pull(x: jax.Array, step: int, fill) -> jax.Array:
+    """``y[p] = x[p + step]``; the last ``step`` entries read ``fill``."""
+    return jnp.pad(x[step:], (0, step), constant_values=fill)
+
+
+def _compact(mask: jax.Array, size: int, *vals: jax.Array):
+    """Move the entries of ``vals`` where ``mask`` holds, in order, to the
+    front; return their first ``size`` slots and the live count.
+
+    Dense vector work, no scatter and no sort (the shift network of the
+    ``z_candidates`` kernel): a log-step prefix count ranks the live
+    entries, and each moves toward the front by its distance, bit by bit,
+    least significant first. The distances never decrease along the list,
+    so two live entries never meet. Slots past the live count hold stale
+    values: callers mask them.
+    """
+    length = mask.shape[0]
+    rank = mask.astype(jnp.int32)
+    step = 1
+    while step < length:  # inclusive prefix count
+        rank = rank + jnp.pad(rank[:-step], (step, 0))
+        step *= 2
+    count = rank[-1]
+    dist = jnp.where(mask, jnp.arange(length, dtype=jnp.int32) - rank + 1, -1)
+    vals = list(vals)
+    step = 1
+    while step < length:
+        d_in = _pull(dist, step, -1)
+        move = (d_in >= 0) & ((d_in & step) != 0)
+        stay = (dist >= 0) & ((dist & step) == 0)
+        vals = [jnp.where(move, _pull(v, step, 0), v) for v in vals]
+        dist = jnp.where(move, d_in, jnp.where(stay, dist, -1))
+        step *= 2
+    if length < size:
+        vals = [jnp.pad(v, (0, size - length)) for v in vals]
+    return [v[:size] for v in vals], count
+
+
+@partial(jax.jit, static_argnames="pair_capacity")
 def apply_flips(
     state: BrightState,
     darken: jax.Array,
     brighten_idx: jax.Array,
     brighten_mask: jax.Array,
+    pair_capacity: int | None = None,
 ) -> BrightState:
-    """Batched O(changed) partition update — the paper's Fig.-3 swap updates
-    vectorized over one z-round, replacing the O(N) ``from_z`` cumsum rebuild
-    on the fused z-engine path.
+    """Batched partition update — the paper's Fig.-3 swap updates vectorized
+    over one z-round, replacing the O(N) ``from_z`` cumsum rebuild on the
+    fused z-engine path.
 
     ``darken`` is a (C,) bool over *bright-buffer slots*: slot ``s`` is
     position ``s`` of ``arr`` and darkens datum ``arr[s]`` (entries at
-    ``s >= num`` are ignored). ``brighten_idx``/``brighten_mask`` name
-    currently-dark data to brighten ((S,) int32 ids; masked entries ignored
-    and may be out-of-range padding). The two sets must be disjoint, which
-    Algorithm 2 guarantees (darken proposals come from the bright set,
-    brighten proposals from the dark set).
+    ``s >= num`` are ignored; ``num <= C`` is required). ``brighten_idx``/
+    ``brighten_mask`` name currently-dark data to brighten ((S,) int32 ids,
+    distinct; masked entries ignored and may be garbage). The two sets must
+    be disjoint, which Algorithm 2 guarantees (darken proposals come from
+    the bright set, brighten proposals from the dark set).
 
     The update is a pairwise swap matching: items that must *enter* the new
     bright region ``[0, num')`` (brightened items stranded at positions
@@ -118,10 +165,27 @@ def apply_flips(
     window ``[num', num)``) are paired one-to-one with items that must
     *leave* it (darkened items at positions ``< num'``, plus still-dark
     items overtaken by a growing window ``[num, num')``) — the two lists
-    provably have equal length — and each pair swaps positions. Everything
-    is masked fixed-shape arithmetic over the (C,)/(S,) buffers plus
-    O(changed) scatters into ``arr``/``tab``: no length-N uniform, cumsum,
-    or compaction ever materializes.
+    provably have equal length P — and each pair swaps positions.
+
+    Cost. Every list is fixed-shape vector work over ``pair_capacity``
+    (default ``max(C, S)``) or C slots: the mover lists are compacted by a
+    shift network, the bright slots and the growth window are read as
+    slices of ``arr``, and the growth window's brightened positions are
+    found by a dense compare. The only index traffic into the N-sized
+    arrays is ``pair_capacity`` gathers of ``tab`` and one scatter of
+    ``2 * pair_capacity`` entries each into ``arr`` and ``tab``. On a TPU
+    each index entry costs about one memory tile whether it is live or
+    dropped, so the update costs O(``pair_capacity``) tiles, not O(P).
+
+    Capacity. P is at most the number m of brightened ids in a growing
+    round and at most the number of darkened slots (<= num <= C) in a
+    shrinking one, so the result is exact whenever m <= ``pair_capacity``.
+    The default is therefore exact for any input; ``pair_capacity = C`` is
+    exact whenever ``num' <= C`` (m <= num'). Otherwise the swaps are
+    skipped: ``arr``/``tab`` stay a valid inverse-permutation pair, ``num``
+    is ``num'``, and the contents are unspecified — the fused z-engine's
+    step flags ``num' > C`` as overflow and the driver discards and re-runs
+    the chunk at a grown capacity.
 
     Matching order is buffer-slot order, which is ``arr``-position order —
     independent of the buffer capacities — so the resulting partition (and
@@ -129,71 +193,73 @@ def apply_flips(
     overflow-re-run contract of the drivers.
     """
     n = state.arr.shape[0]
-    sd = darken.shape[0]
     sb = brighten_idx.shape[0]
-    slots = jnp.arange(sd, dtype=jnp.int32)
-    darken = darken & (slots < state.num)
+    pc = max(darken.shape[0], sb) if pair_capacity is None else pair_capacity
+    c = min(darken.shape[0], n)  # slots past N are never bright
+    slots = jnp.arange(c, dtype=jnp.int32)
+    darken = darken[:c] & (slots < state.num)
+    arr_c = state.arr[:c]
     k = jnp.sum(darken).astype(jnp.int32)
     m = jnp.sum(brighten_mask).astype(jnp.int32)
     num2 = state.num - k + m
 
-    b_idx = jnp.clip(brighten_idx.astype(jnp.int32), 0, n - 1)
-    pos_b = jnp.take(state.tab, b_idx)
+    # Brightened ids, in buffer order, in pair_capacity slots (exact while
+    # m <= pair_capacity), then their positions: the only gather.
+    b_idx = jnp.where(brighten_mask, brighten_idx.astype(jnp.int32), n)
+    if sb > pc:
+        (b_idx,), _ = _compact(brighten_mask, pc, b_idx)
+        b_live = jnp.arange(pc, dtype=jnp.int32) < m
+    else:
+        b_idx = jnp.pad(b_idx, (0, pc - sb), constant_values=n)
+        b_live = jnp.pad(brighten_mask, (0, pc - sb))
+    pos_b = state.tab.at[b_idx].get(mode="fill", fill_value=n)
+    pos_b = jnp.where(b_live, pos_b, -1)
+
+    # Growth window [num, num'), read from arr[lo : lo + w]: the clamp of
+    # lo keeps the slice inside arr, and positions before num are masked.
+    w = min(pc, n)
+    lo = jnp.clip(state.num, 0, n - w)
+    win_item = jax.lax.dynamic_slice_in_dim(state.arr, lo, w)
+    win_pos = lo + jnp.arange(w, dtype=jnp.int32)
+    brightened = jnp.any(win_pos[:, None] == pos_b[None, :], axis=1)
 
     # --- movers INTO [0, num') ---------------------------------------------
     # (a) brightened items currently parked at positions >= num'
-    ma_mask = brighten_mask & (pos_b >= num2)
-    # (b) shrink window [num', num): still-bright residents must relocate.
-    #     Window positions are < num <= C, i.e. bright-buffer slots, so
-    #     "still bright" is just ~darken at that slot.
-    w = num2 + slots
-    w_in = w < state.num  # empty when the bright set grows (num' >= num)
-    w_cl = jnp.clip(w, 0, sd - 1)
-    wb_mask = w_in & ~jnp.take(darken, w_cl)
-    wb_item = jnp.take(state.arr, jnp.clip(w, 0, n - 1))
-    in_item = jnp.concatenate([jnp.where(ma_mask, b_idx, n),
-                               jnp.where(wb_mask, wb_item, n)])
-    in_pos = jnp.concatenate([jnp.where(ma_mask, pos_b, n),
-                              jnp.where(wb_mask, w, n)])
-    in_mask = jnp.concatenate([ma_mask, wb_mask])
+    # (b) shrink window [num', num): still-bright residents must relocate
+    #     (empty when the bright set grows).
+    in_mask = jnp.concatenate([
+        b_live & (pos_b >= num2),
+        (slots >= num2) & (slots < state.num) & ~darken,
+    ])
+    (bi, bp), n_in = _compact(in_mask, pc,
+                              jnp.concatenate([b_idx, arr_c]),
+                              jnp.concatenate([pos_b, slots]))
 
     # --- movers OUT of [0, num') -------------------------------------------
     # (a) darkened items currently inside the new bright region
-    da_mask = darken & (slots < num2)
-    da_item = jnp.take(state.arr, jnp.minimum(slots, n - 1))
-    # (b) growth window [num, num'): still-dark residents must relocate.
-    #     Membership "was this position's item brightened" via an O(S)
-    #     scatter of the brighten positions into window coordinates
-    #     (masked / out-of-window entries go to the sentinel slot and drop).
-    v = state.num + jnp.arange(sb, dtype=jnp.int32)
-    v_in = v < num2  # empty when the bright set shrinks
-    v_brightened = (
-        jnp.zeros(sb, bool)
-        .at[jnp.where(brighten_mask, pos_b - state.num, sb)]
-        .set(True, mode="drop")
-    )
-    vd_mask = v_in & ~v_brightened
-    vd_item = jnp.take(state.arr, jnp.clip(v, 0, n - 1))
-    out_item = jnp.concatenate([jnp.where(da_mask, da_item, n),
-                                jnp.where(vd_mask, vd_item, n)])
-    out_pos = jnp.concatenate([jnp.where(da_mask, slots, n),
-                               jnp.where(vd_mask, v, n)])
-    out_mask = jnp.concatenate([da_mask, vd_mask])
+    # (b) growth window [num, num'): still-dark residents must relocate
+    #     (empty when the bright set shrinks).
+    out_mask = jnp.concatenate([
+        darken & (slots < num2),
+        (win_pos >= state.num) & (win_pos < num2) & ~brightened,
+    ])
+    (di, dp), n_out = _compact(out_mask, pc,
+                               jnp.concatenate([arr_c, win_item]),
+                               jnp.concatenate([slots, win_pos]))
 
-    # --- compact to prefix order and swap pairwise -------------------------
-    def compact(item, pos, mask):
-        size = item.shape[0]
-        dest = jnp.where(mask, jnp.cumsum(mask) - 1, size)
-        pad = jnp.full(size, n, jnp.int32)
-        return (pad.at[dest].set(item, mode="drop"),
-                pad.at[dest].set(pos, mode="drop"))
-
-    bi, bp = compact(in_item, in_pos, in_mask)
-    di, dp = compact(out_item, out_pos, out_mask)
-    # |in| == |out| always, so pairing i-th with i-th is a clean swap;
-    # sentinel (n) positions/items beyond the pair count drop harmlessly.
-    arr = state.arr.at[dp].set(bi, mode="drop").at[bp].set(di, mode="drop")
-    tab = state.tab.at[bi].set(dp, mode="drop").at[di].set(bp, mode="drop")
+    # --- swap pairwise ------------------------------------------------------
+    # Pair i-th with i-th. Unmatched slots (and every slot when a list did
+    # not fit) get distinct out-of-range indices, so the scatters drop them
+    # and every index is unique.
+    pairs = jnp.where((n_in == n_out) & (n_in <= pc), n_in, 0)
+    t = jnp.arange(pc, dtype=jnp.int32)
+    live = t < pairs
+    bi, bp = jnp.where(live, bi, n + t), jnp.where(live, bp, n + t)
+    di, dp = jnp.where(live, di, n + pc + t), jnp.where(live, dp, n + pc + t)
+    arr = state.arr.at[jnp.concatenate([dp, bp])].set(
+        jnp.concatenate([bi, di]), mode="drop", unique_indices=True)
+    tab = state.tab.at[jnp.concatenate([bi, di])].set(
+        jnp.concatenate([dp, bp]), mode="drop", unique_indices=True)
     return BrightState(arr=arr, tab=tab, num=num2)
 
 

@@ -348,8 +348,13 @@ def _fused_z_update(
       * candidate δ routes through :func:`_candidate_delta` (the fused
         bright-GLM kernel under ``backend="pallas"``);
       * the partition is maintained incrementally by
-        :func:`repro.core.brightness.apply_flips` — O(changed) swaps, no
-        full-N cumsum rebuild.
+        :func:`repro.core.brightness.apply_flips` with
+        ``pair_capacity = spec.capacity`` — no full-N cumsum rebuild; its
+        index traffic into the N-sized ``arr``/``tab`` is bounded by the
+        bright capacity C (about one memory tile per index entry on a TPU),
+        and ``delta_full`` takes one scatter over the candidate slots. A
+        step whose bright count exceeds C leaves an unspecified partition,
+        and is flagged overflow, discarded and re-run by the driver.
 
     Keying on datum indices keeps the trajectory bitwise invariant to
     capacity and chunk size (the same contract as the jnp engine), but the
@@ -384,19 +389,20 @@ def _fused_z_update(
         )
     with jax.named_scope("flymc.z.delta"):
         overflow_c = n_cand > spec.cand_capacity
-        mask_c = jnp.arange(spec.cand_capacity, dtype=jnp.int32) < n_cand
+        slot_c = jnp.arange(spec.cand_capacity, dtype=jnp.int32)
+        mask_c = slot_c < n_cand
         nb = jnp.minimum(n_cand, spec.cand_capacity)
         delta_c = _candidate_delta(spec, data, theta, cand_idx, nb)
         u3 = counter_uniform(kw, DRAW_BRIGHT, jnp.clip(cand_idx, 0, n - 1))
         brighten = mask_c & (jnp.log(u3) + log_q < log_expm1(delta_c))
     with jax.named_scope("flymc.z.flips"):
-        delta_full = delta_full.at[cand_idx].set(
-            jnp.where(mask_c, delta_c,
-                      delta_full[jnp.clip(cand_idx, 0, n - 1)]),
-            mode="drop",
-        )
-        bright_new = brightness.apply_flips(bright, darken, cand_idx,
-                                            brighten)
+        # Padded candidate slots hold N; give each its own out-of-range
+        # index so the scatter drops it and every index is unique.
+        cand_at = jnp.where(mask_c, cand_idx, n + slot_c)
+        delta_full = delta_full.at[cand_at].set(
+            delta_c, mode="drop", unique_indices=True)
+        bright_new = brightness.apply_flips(
+            bright, darken, cand_idx, brighten, pair_capacity=spec.capacity)
     return bright_new, delta_full, n_cand, overflow_c
 
 
@@ -476,9 +482,12 @@ def flymc_step(
         queries_theta = info.n_evals * state.bright.num
         # δ at (possibly) new θ for the bright buffer, from the kernel's aux
         # cache.
-        delta_full = state.delta_full.at[idx].set(
-            jnp.where(mask, new_sampler.aux, state.delta_full[idx])
-        )
+        # idx is a slice of the permutation arr: unique, and the padding
+        # slots get their own out-of-range indices, which the scatter drops.
+        n = state.delta_full.shape[0]
+        pad_idx = n + jnp.arange(spec.capacity, dtype=jnp.int32)
+        delta_full = state.delta_full.at[jnp.where(mask, idx, pad_idx)].set(
+            new_sampler.aux, mode="drop", unique_indices=True)
 
     # ---- z | θ -------------------------------------------------------------
     with jax.named_scope("flymc.z"):

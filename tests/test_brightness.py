@@ -219,3 +219,151 @@ def test_apply_flips_noop_round():
     np.testing.assert_array_equal(np.asarray(out.arr), np.asarray(s.arr))
     np.testing.assert_array_equal(np.asarray(out.tab), np.asarray(s.tab))
     assert int(out.num) == int(s.num)
+
+
+# ---------------------------------------------------------------------------
+# apply_flips at pair_capacity = C against the (C + S)-slot update it replaced
+# ---------------------------------------------------------------------------
+
+
+@jax.jit
+def _apply_flips_reference(state, darken, brighten_idx, brighten_mask):
+    """The partition update as it was before ``pair_capacity``: every mover
+    list spans C + S slots. Kept verbatim as the bitwise reference."""
+    n = state.arr.shape[0]
+    sd = darken.shape[0]
+    sb = brighten_idx.shape[0]
+    slots = jnp.arange(sd, dtype=jnp.int32)
+    darken = darken & (slots < state.num)
+    k = jnp.sum(darken).astype(jnp.int32)
+    m = jnp.sum(brighten_mask).astype(jnp.int32)
+    num2 = state.num - k + m
+
+    b_idx = jnp.clip(brighten_idx.astype(jnp.int32), 0, n - 1)
+    pos_b = jnp.take(state.tab, b_idx)
+
+    ma_mask = brighten_mask & (pos_b >= num2)
+    w = num2 + slots
+    w_in = w < state.num
+    w_cl = jnp.clip(w, 0, sd - 1)
+    wb_mask = w_in & ~jnp.take(darken, w_cl)
+    wb_item = jnp.take(state.arr, jnp.clip(w, 0, n - 1))
+    in_item = jnp.concatenate([jnp.where(ma_mask, b_idx, n),
+                               jnp.where(wb_mask, wb_item, n)])
+    in_pos = jnp.concatenate([jnp.where(ma_mask, pos_b, n),
+                              jnp.where(wb_mask, w, n)])
+    in_mask = jnp.concatenate([ma_mask, wb_mask])
+
+    da_mask = darken & (slots < num2)
+    da_item = jnp.take(state.arr, jnp.minimum(slots, n - 1))
+    v = state.num + jnp.arange(sb, dtype=jnp.int32)
+    v_in = v < num2
+    v_brightened = (
+        jnp.zeros(sb, bool)
+        .at[jnp.where(brighten_mask, pos_b - state.num, sb)]
+        .set(True, mode="drop")
+    )
+    vd_mask = v_in & ~v_brightened
+    vd_item = jnp.take(state.arr, jnp.clip(v, 0, n - 1))
+    out_item = jnp.concatenate([jnp.where(da_mask, da_item, n),
+                                jnp.where(vd_mask, vd_item, n)])
+    out_pos = jnp.concatenate([jnp.where(da_mask, slots, n),
+                               jnp.where(vd_mask, v, n)])
+    out_mask = jnp.concatenate([da_mask, vd_mask])
+
+    def compact(item, pos, mask):
+        size = item.shape[0]
+        dest = jnp.where(mask, jnp.cumsum(mask) - 1, size)
+        pad = jnp.full(size, n, jnp.int32)
+        return (pad.at[dest].set(item, mode="drop"),
+                pad.at[dest].set(pos, mode="drop"))
+
+    bi, bp = compact(in_item, in_pos, in_mask)
+    di, dp = compact(out_item, out_pos, out_mask)
+    arr = state.arr.at[dp].set(bi, mode="drop").at[bp].set(di, mode="drop")
+    tab = state.tab.at[bi].set(dp, mode="drop").at[di].set(bp, mode="drop")
+    return brightness.BrightState(arr=arr, tab=tab, num=num2)
+
+
+def _bounded_flip_case(rng, n, grow, cap_over_n, s_factor, sorted_ids):
+    """A z-round whose new bright count fits the bright capacity C.
+
+    ``grow`` picks a growing (m > k) or shrinking (m < k) round;
+    ``cap_over_n`` lets C exceed N; ``s_factor`` sizes the brighten buffer
+    S against C; ``sorted_ids`` lists the brightened ids in ``arr``-position
+    order, as the candidate kernel emits them, else in random order. Masked
+    brighten slots hold garbage, in and out of range."""
+    z = rng.random(n) < rng.uniform(0.1, 0.7)
+    s = brightness.from_z(jnp.asarray(z))
+    arr = np.asarray(s.arr)
+    num = int(s.num)
+    n_dark = n - num
+    cap = n + int(rng.integers(1, 6)) if cap_over_n else int(
+        rng.integers(max(1, num), n + 1))
+    if grow:  # k < m and num - k + m <= cap
+        k = int(rng.integers(0, num + 1))
+        hi = min(n_dark, cap - num + k)
+        if hi <= k:
+            k, hi = 0, min(n_dark, cap - num)
+        m = int(rng.integers(min(k + 1, hi), hi + 1))
+    else:  # m < k
+        k = int(rng.integers(1, num + 1)) if num else 0
+        m = int(rng.integers(0, min(k - 1, n_dark) + 1)) if num else 0
+    sb = max(m, int(s_factor * cap) + int(rng.integers(0, 4)), 1)
+    darken = np.zeros(cap, bool)
+    darken[rng.choice(num, k, replace=False)] = True
+    darken[num:] = rng.random(cap - num) < 0.5  # ignored: slots >= num
+    chosen = rng.choice(arr[num:], m, replace=False).astype(np.int32)
+    if sorted_ids:
+        chosen = arr[num:][np.isin(arr[num:], chosen)].astype(np.int32)
+    slots = rng.choice(sb, m, replace=False)
+    slots.sort()
+    b_mask = np.zeros(sb, bool)
+    b_mask[slots] = True
+    b_idx = rng.integers(-3, 2 * n + 5, sb).astype(np.int32)  # garbage
+    b_idx[slots] = chosen
+    assert num - k + m <= cap
+    return s, darken, b_idx, b_mask
+
+
+@settings(deadline=None, max_examples=40)
+@given(st.integers(0, 10_000), st.integers(3, 48), st.booleans(),
+       st.booleans(), st.integers(0, 3), st.booleans())
+def test_apply_flips_bright_capacity_matches_reference(
+    seed, n, grow, cap_over_n, s_size, sorted_ids
+):
+    """With pair_capacity = C, every round whose new bright count fits C
+    gives the reference's arr, tab and num bit for bit: growing and
+    shrinking rounds, C > N, S far above C, garbage in masked slots."""
+    rng = np.random.default_rng(seed)
+    s_factor = (0.0, 0.5, 1.0, 6.0)[s_size]
+    s, darken, b_idx, b_mask = _bounded_flip_case(
+        rng, n, grow, cap_over_n, s_factor, sorted_ids)
+    args = (s, jnp.asarray(darken), jnp.asarray(b_idx), jnp.asarray(b_mask))
+    ref = _apply_flips_reference(*args)
+    out = brightness.apply_flips(*args, pair_capacity=darken.shape[0])
+    np.testing.assert_array_equal(np.asarray(out.arr), np.asarray(ref.arr))
+    np.testing.assert_array_equal(np.asarray(out.tab), np.asarray(ref.tab))
+    assert int(out.num) == int(ref.num)
+    assert brightness.check_invariants(out)
+
+
+def test_apply_flips_over_capacity_returns_a_permutation():
+    """num' > C: the result's contents are unspecified, but the call
+    returns, num is num', and arr/tab stay an inverse-permutation pair."""
+    n, cap = 40, 6
+    z = np.zeros(n, bool)
+    z[[3, 9, 17]] = True
+    s = brightness.from_z(jnp.asarray(z))
+    dark = np.flatnonzero(~z)
+    for m in (cap, cap + 3, len(dark)):  # num' = 1 + m > C; m = C fits
+        b_idx = np.full(len(dark) + 4, n, np.int32)
+        b_idx[:m] = dark[:m]
+        darken = np.zeros(cap, bool)
+        darken[:2] = True
+        out = brightness.apply_flips(
+            s, jnp.asarray(darken), jnp.asarray(b_idx),
+            jnp.asarray(np.arange(len(b_idx)) < m), pair_capacity=cap,
+        )
+        assert int(out.num) == 1 + m > cap
+        assert brightness.check_invariants(out)

@@ -5,7 +5,9 @@ topology, so Mosaic's refusals (unaligned DMA slices, scalar stores to
 VMEM, loops it cannot legalize, more SMEM or VMEM than the chip has) fail
 here rather than on the chip. Every case passes ``interpret=False`` and
 compiles at the Table-1 shape and capacity (``benchmarks/table1.py``), for
-one chain and for the 8-chain grid. The topology is described inside a
+one chain and for the 8-chain grid. The fused z-update around them is
+compiled at the c64 cell's shape to bound its index traffic into the
+N-sized chain state. The topology is described inside a
 fixture (one process may hold the TPU library at a time, so nothing here
 touches it at import), and the persistent compilation cache is off around
 these compiles: an entry written without a chip cannot be read back.
@@ -95,3 +97,76 @@ def test_z_candidates_compiles_for_v5e(one_chip, n, chains):
 
     _compile(fn, [s((n,)), s(()), s((2,))], chains,
              batched=(True, True, True))
+
+
+def _dims(shape: str) -> list[int]:
+    """``s32[64,1024,2]{...}`` → [64, 1024, 2]."""
+    inner = shape[shape.index("[") + 1:shape.index("]")]
+    return [int(x) for x in inner.split(",") if x]
+
+
+def _index_entries(hlo: str, op: str, operand_elems: int) -> int:
+    """Index entries of every ``op`` (scatter or gather) in the compiled
+    module whose operand holds ``operand_elems`` elements."""
+    import math
+    import re
+
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+) ", hlo, re.M))
+    total = 0
+    for operands, attrs in re.findall(rf"= .*? {op}\(([^)]*)\)(.*)$", hlo,
+                                      re.M):
+        operand, indices = re.findall(r"%[\w.\-]+", operands)[:2]
+        if math.prod(_dims(shapes[operand])) != operand_elems:
+            continue
+        dims = _dims(shapes[indices])
+        vec = int(re.search(r"index_vector_dim=(\d+)", attrs).group(1))
+        total += math.prod(dims) // (dims[vec] if vec < len(dims) else 1)
+    return total
+
+
+def test_fused_z_update_index_traffic_for_v5e(one_chip, monkeypatch):
+    """The fused z-update's partition swaps and δ-cache write, vmapped over
+    the c64 cell's 64 chains at the logistic Table-1 shape with C = S =
+    1,024. On a TPU each scatter or gather index entry costs about one
+    memory tile whether it is live or dropped, so the entries into the
+    chains' N-sized state (arr, tab, delta_full) are what the flips cost:
+    per chain at most 4·C + S scattered (arr and tab one 2·C-entry scatter
+    each, delta_full S) and at most C gathered (tab at the brightened ids;
+    the contiguous windows of arr are slices). No sort."""
+    import re
+
+    from repro.core import bounds as bounds_lib
+    from repro.core import brightness, flymc
+    from repro.core.bounds import GLMData
+    from repro.kernels import common
+
+    # The kernels pick interpret mode from the CPU backend this process
+    # runs on; compile the real ones.
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+
+    n, d, chains, cap = _LOGISTIC.n, _LOGISTIC.d, 64, 1024
+    spec = flymc.FlyMCSpec(
+        bound=bounds_lib.get_bound("logistic"), log_prior=lambda th: 0.0,
+        capacity=cap, cand_capacity=cap, backend="pallas", z_backend="fused",
+    )
+    s = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    c = lambda shape, dt=jnp.float32: s((chains,) + shape, dt)
+    data = GLMData(x=s((n, d)), t=s((n,)), xi=s((n,)), x_rows=s((n, 1, 128)))
+
+    def z_update(data, key, theta, arr, tab, num, delta_full, delta_bright):
+        bright = brightness.BrightState(arr, tab, num)
+        return flymc._fused_z_update(spec, data, key, theta, bright,
+                                     delta_full, delta_bright)
+
+    args = [data, c((2,), jnp.uint32), c((d,)), c((n,), jnp.int32),
+            c((n,), jnp.int32), c((), jnp.int32), c((n,)), c((cap,))]
+    batched = jax.vmap(z_update, in_axes=(None,) + (0,) * 7)
+    hlo = jax.jit(batched).lower(*args).compile().as_text()
+
+    assert hlo.count("tpu_custom_call") >= 2  # z_candidates, bright_glm
+    assert re.findall(r" sort\(", hlo) == []
+    scattered = _index_entries(hlo, "scatter", chains * n) / chains
+    gathered = _index_entries(hlo, "gather", chains * n) / chains
+    assert 0 < scattered <= 4 * cap + cap, scattered
+    assert 0 < gathered <= cap, gathered
